@@ -32,6 +32,9 @@ def run(sparsities=(1e-5, 1e-4, 1e-3), size=200, rank=16, n_iter=2) -> list:
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("fig6_sparsity: sparsity,nnz,sparse_hooi_s,dense_hooi_s,speedup")
     for r in run():
         print(f"{r['sparsity']:.0e},{r['nnz']},{r['sparse_s']:.4f},"

@@ -159,6 +159,9 @@ def main(argv: Optional[list] = None) -> int:
     import jax
 
     from benchmarks.common import registry_snapshot
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     # the overhead gate divides a FIXED per-segment cost (one host sync +
     # one ~1ms checkpoint write) by five sweeps of compute, so it is only

@@ -382,6 +382,9 @@ def main(argv: Optional[list] = None) -> int:
     import jax
 
     from benchmarks.common import registry_snapshot
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro import tucker
     from repro.sparse.layout import bucket_nnz
 

@@ -131,13 +131,11 @@ def main(argv: Optional[list] = None) -> int:
     import jax
 
     from benchmarks.common import registry_snapshot
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
-    from repro.utils.compat import has_shard_map
-
-    if not has_shard_map():
-        print("shard_map unavailable in this jax install; nothing to bench")
-        return 0
     device_counts = sorted({d for d in (1, 2, 4) if d <= n_dev})
 
     if args.smoke:

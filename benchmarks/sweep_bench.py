@@ -349,7 +349,9 @@ def main(argv: Optional[list] = None) -> int:
 
     import jax
     from repro.core.engine import available_engines
+    from repro.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     engines = available_engines() if args.engine == "both" else [args.engine]
 
     if args.smoke:

@@ -42,6 +42,9 @@ def run(sizes=(50, 100, 200), rank=16, n_iter=3) -> list:
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("table2_accuracy: size,svd_err,qrp_err,qrp_gram_err,agree")
     for r in run():
         print(f"{r['size']},{r['svd']:.4e},{r['qrp']:.4e},{r['qrp_gram']:.4e},{r['agree']}")

@@ -58,6 +58,9 @@ def run(ranks=(32, 64, 128, 256), nnz=128, engine: str = "both",
 
 def main(argv=None):
     from benchmarks.common import add_engine_arg
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     # argv=None (e.g. from benchmarks.run) means "no CLI args": don't let
     # argparse pick up the aggregator's own sys.argv.

@@ -48,6 +48,9 @@ def run(names=("amazon", "nell2", "matmul", "angiogram")) -> list:
 
 
 def main():
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("table5_realworld: name,shape,nnz,ours_cpu_s,rel_err,kron_calls,"
           "paper_cpu_s,paper_hybrid_s,paper_dense_fpga_s")
     for r in run():

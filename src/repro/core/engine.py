@@ -22,7 +22,6 @@ can be selected here.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 import weakref
 from typing import Dict, List, Optional, Sequence
 
@@ -54,37 +53,15 @@ _SCHEDULE_BUILDS = {
 }
 
 
-def pallas_available() -> bool:
-    """Can the Pallas kernel path run here at all? (Import-level check; on
-    non-TPU backends the kernels run in interpret mode.)"""
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-    except Exception:  # pragma: no cover - exercised via monkeypatch in tests
-        return False
-    return True
-
-
 def resolve_engine(engine: str = "auto") -> str:
-    """Map a requested engine to the one that will actually run.
-
-    ``auto`` picks ``pallas`` on TPU and ``xla`` elsewhere. An explicit
-    ``pallas`` request is honored even off-TPU (interpret mode) unless the
-    Pallas import itself is unavailable, in which case we warn and fall back
-    to ``xla`` so CPU-only hosts stay green.
+    """Map a requested engine to the one that will actually run: ``auto``
+    picks ``pallas`` on TPU and ``xla`` elsewhere; an explicit engine is
+    honored as asked (``pallas`` off-TPU runs the kernels in interpret mode).
     """
     if engine not in ENGINES:
         raise ValueError(f"engine must be one of {ENGINES}, got {engine!r}")
     if engine == "auto":
         return "pallas" if jax.default_backend() == "tpu" else "xla"
-    if engine == "pallas" and not pallas_available():
-        warnings.warn(
-            "Pallas is unavailable in this jax install; sparse sweep falling "
-            "back to the XLA engine.",
-            RuntimeWarning,
-            stacklevel=2,
-        )
-        return "xla"
     return engine
 
 
@@ -363,8 +340,5 @@ def make_engine(
 
 
 def available_engines() -> List[str]:
-    """Engines that can actually execute on this host (test harness helper)."""
-    out = ["xla"]
-    if pallas_available():
-        out.append("pallas")
-    return out
+    """The concrete engines (test harness helper): both run on every host."""
+    return ["xla", "pallas"]

@@ -524,8 +524,9 @@ def _batched_scan_sweeps(
 
     def one(idx, val, key):
         fs = tuple(init_factors(shape, ranks, key, dtype=dtype))
-        # identical formula to the per-tensor path (square of the norm), so
-        # batched results are bit-compatible with sequential calls.
+        # identical formula to the per-tensor path (square of the norm); the
+        # vmapped program still reduces in its own order, so batched results
+        # match sequential calls to the last bit or so, not bitwise.
         xn = jnp.square(jnp.sqrt(jnp.sum(jnp.square(val.astype(jnp.float32)))))
         return _scan_sweeps_impl(
             idx, val, fs, xn, tol, None,
@@ -579,8 +580,6 @@ def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.utils.compat import shard_map
-
     nnz_axes = tuple(nnz_axes)
     shape, ranks = tuple(shape), tuple(ranks)
     n = len(shape)
@@ -629,7 +628,7 @@ def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
             P(None),  # fit history
             (P(), P(), P()),  # carry out: prev_err, done, n_done
         )
-        inner = shard_map(
+        inner = jax.shard_map(
             segment_body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
@@ -666,7 +665,7 @@ def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
         core_spec,  # core
         P(None),  # fit history
     )
-    inner = shard_map(
+    inner = jax.shard_map(
         sweep_body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )
@@ -709,8 +708,7 @@ def hooi_sparse(
       use_kron_reuse: enable the paper's Kronecker-row dedup (Sec. III-C)
         on the XLA engine (the Pallas schedule has its own reuse layout).
       engine: 'xla', 'pallas' or 'auto' — how the sweep's hot loops execute
-        (see ``core.engine``). 'auto' picks pallas on TPU, xla elsewhere;
-        'pallas' without a usable Pallas install warns and falls back. A
+        (see ``core.engine``). 'auto' picks pallas on TPU, xla elsewhere. A
         prebuilt :class:`~repro.core.engine.SweepEngine` is also accepted and
         reuses its cached (device-resident) schedules across calls.
       pipeline: 'scan' (default) compiles the whole multi-sweep loop into a

@@ -419,6 +419,8 @@ def autotune(
     zero trials (``COUNTERS['table_hits']`` bumps). Cold path: prune + rank
     candidates, time the top ``max_trials`` (default always among them),
     persist the winner atomically, return it."""
+    import jax
+
     own_table = table is None
     if own_table:
         table = TuningTable()
@@ -443,7 +445,12 @@ def autotune(
                     cfg, shape, ranks, nnz,
                     dtype=dtype, precision=precision, interpret=interpret,
                 )
-            except Exception:  # an untunable candidate loses, never crashes
+            except Exception:
+                # off-TPU an untunable candidate loses in silence. On the
+                # chip the VMEM model already pruned what cannot fit, so a
+                # failed trial is a compiler refusal that must surface.
+                if jax.default_backend() == "tpu":
+                    raise
                 continue
             if ms < best_ms:
                 best_cfg, best_ms = cfg, ms

@@ -58,16 +58,21 @@ def _cast_operands(precision: str, *arrays):
 # ---------------------------------------------------------------------------
 
 
-def _kron_kernel(a_ref, b_ref, v_ref, o_ref):
-    a = a_ref[...]  # (BN, Ra)
-    b = b_ref[...]  # (BN, Rb)
-    v = v_ref[...]  # (BN, 1)
+def _kron_rows(a, b):
+    """Outer product per nonzero, (BN, Ra) x (BN, Rb) -> (BN, Ra*Rb) f32; Rb
+    varies fastest (paper Alg. 4 line 4: c[R3*i + j] = a[i] * b[j]). The
+    operand tiles widen to f32 before the 3-D broadcast: Mosaic has no
+    bf16 (BN, R) -> (BN, R, 1) shape cast, and a product of two bf16 values
+    is exact in f32, so bf16 loads from HBM are kept at no cost in accuracy."""
+    a = a.astype(jnp.float32)
+    b = b.astype(jnp.float32)
     bn, ra = a.shape
-    rb = b.shape[1]
-    # outer product per nonzero; Rb varies fastest (paper Alg. 4 line 4:
-    # c[R3*i + j] = a[i] * b[j]).
-    kron = (a[:, :, None] * b[:, None, :]).reshape(bn, ra * rb)
-    o_ref[...] = (kron * v).astype(o_ref.dtype)
+    return (a[:, :, None] * b[:, None, :]).reshape(bn, ra * b.shape[1])
+
+
+def _kron_kernel(a_ref, b_ref, v_ref, o_ref):
+    kron = _kron_rows(a_ref[...], b_ref[...])
+    o_ref[...] = (kron * v_ref[...]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret", "precision"))
@@ -77,7 +82,7 @@ def kron_contrib_pallas(
     v: jax.Array,
     *,
     bn: int = DEFAULT_BN,
-    interpret: bool = True,
+    interpret: bool,
     precision: str = "fp32",
 ) -> jax.Array:
     """contrib[t] = v[t] * (a[t] (x) b[t]) for a block-padded batch.
@@ -209,7 +214,7 @@ def scatter_rows_pallas(
     plan: ScatterPlan,
     n_rows: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Y_(n) accumulation: sum contrib rows into their target rows.
 
@@ -259,13 +264,9 @@ def _fused_kernel(blkmap_ref, first_ref, a_ref, b_ref, v_ref, rel_ref, o_ref):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = a_ref[...]  # (BN, Ra)
-    b = b_ref[...]  # (BN, Rb)
-    v = v_ref[...]  # (BN, 1) f32, zero on padding rows
-    bn, ra = a.shape
-    rb = b.shape[1]
-    kron = (a[:, :, None] * b[:, None, :]).reshape(bn, ra * rb)
-    contrib = kron.astype(jnp.float32) * v
+    # (BN, 1) f32 values, zero on padding rows
+    contrib = _kron_rows(a_ref[...], b_ref[...]) * v_ref[...]
+    bn = contrib.shape[0]
     rel = rel_ref[...]  # (BN, 1) int32
     bi = o_ref.shape[0]
     onehot = (rel == jax.lax.broadcasted_iota(jnp.int32, (bn, bi), 1)).astype(
@@ -310,7 +311,7 @@ def fused_kron_scatter_pallas(
     plan,
     n_rows: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     precision: str = "fp32",
 ) -> jax.Array:
     """Y_(n)[i_n] += v * (a (x) b), fused: Alg. 4 + Eq. 13 in one kernel.
@@ -362,13 +363,9 @@ def _mega_kernel(
     def _init_rows():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    a = a_ref[...]  # (BN, Ra)
-    b = b_ref[...]  # (BN, Rb)
-    v = v_ref[...]  # (BN, 1) f32, zero on padding rows
-    bn, ra = a.shape
-    rb = b.shape[1]
-    kron = (a[:, :, None] * b[:, None, :]).reshape(bn, ra * rb)
-    contrib = kron.astype(jnp.float32) * v
+    # (BN, 1) f32 values, zero on padding rows
+    contrib = _kron_rows(a_ref[...], b_ref[...]) * v_ref[...]
+    bn = contrib.shape[0]
     rel = rel_ref[...]  # (BN, 1) int32
     bi = y_ref.shape[0]
     onehot = (rel == jax.lax.broadcasted_iota(jnp.int32, (bn, bi), 1)).astype(
@@ -433,7 +430,7 @@ def fused_kron_scatter_ttm_pallas(
     plan,
     n_rows: int,
     *,
-    interpret: bool = True,
+    interpret: bool,
     precision: str = "fp32",
 ) -> jax.Array:
     """G = U^T Y where Y[i_n] += v * (a (x) b) — Alg. 4 + Eq. 13 + Eq. 12

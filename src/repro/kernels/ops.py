@@ -1,8 +1,8 @@
 """Jit'd public wrappers over the Pallas kernels.
 
-``interpret`` defaults to True unless a TPU is present — this container is
-CPU-only, so kernels validate in interpret mode; on a v5e pod the same call
-sites compile to Mosaic.
+``interpret`` defaults to True exactly when the backend is not a TPU: off the
+chip the kernels validate in interpret mode, and on a TPU the same call sites
+compile to Mosaic.
 """
 from __future__ import annotations
 

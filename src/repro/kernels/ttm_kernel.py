@@ -63,9 +63,9 @@ def ttm_pallas(
     y: jax.Array,
     u: jax.Array,
     *,
+    interpret: bool,
     bl: int = DEFAULT_BL,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
     precision: str = "fp32",
 ) -> jax.Array:
     """``G = Y @ U^T`` — the paper's TTM (Eq. 12) as a tiled Pallas kernel.
@@ -74,8 +74,8 @@ def ttm_pallas(
       y: (L, I3) unfolded dense tensor (L = prod of the other ranks).
       u: (R3, I3) factor (transposed application, Eq. 11).
       bl, bk: VMEM block shape knobs (rows / contraction).
-      interpret: run the kernel body in interpret mode (CPU container);
-        on a real TPU pass False.
+      interpret: run the kernel body in interpret mode (off-TPU only; the
+        callers pass ``kernels.ops.default_interpret()``).
       precision: "fp32", or "bf16_fp32acc" for bf16 operand loads/multiplies
         with the f32 VMEM scratch accumulator (the MXU's native mixed mode).
 

@@ -30,7 +30,6 @@ from repro.launch.mesh import make_production_mesh
 from repro.models import model as model_lib
 from repro.optim.compression import compress_matrix, decompress_matrix
 from repro.utils import hlo as hlo_lib
-from repro.utils.compat import shard_map
 
 
 def grad_matrices(cfg):
@@ -79,7 +78,7 @@ def main():
     shapes, shardings = make_inputs()
     results = {}
     for name, fn in (("raw", raw_sync), ("qrp_compressed", compressed_sync)):
-        sm = shard_map(
+        sm = jax.shard_map(
             fn, mesh=mesh,
             in_specs=tuple(P("pod", None, None) for _ in mats),
             out_specs=tuple(P(None, None) for _ in mats),

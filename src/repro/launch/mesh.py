@@ -8,8 +8,7 @@ runtime.
 from __future__ import annotations
 
 import jax
-
-from repro.utils.compat import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -21,11 +20,12 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Whatever devices exist, as a (data, model) mesh — smoke tests (1 CPU
     device) and small real runs."""
     n = len(jax.devices())
-    return make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -17,7 +17,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
 from repro.configs.base import ModelConfig
-from repro.utils import compat
 from repro.models import transformer as tfm
 from repro.models.layers import pack_bf16, rmsnorm, softmax_cross_entropy, unpack_bf16
 from repro.models.sharding import ShardingRules, constrain, spec_for
@@ -229,7 +228,7 @@ def _barrier(tree):
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves:
         return tree
-    leaves = list(compat.optimization_barrier(tuple(leaves)))
+    leaves = list(jax.lax.optimization_barrier(tuple(leaves)))
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 

@@ -25,7 +25,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.models.sharding import ShardingRules, _resolve_axes
-from repro.utils.compat import shard_map
 
 
 def _capacity(tokens_local: int, cfg: ModelConfig) -> int:
@@ -93,7 +92,7 @@ def moe_block_decode_gathered(
         return y_loc[:, None, :].astype(x_loc.dtype), aux
 
     ep_spec = ep_axes if len(ep_axes) != 1 else ep_axes[0]
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(P(batch_ax, None, None), P(None, None),
@@ -239,7 +238,7 @@ def moe_block(
         out = jnp.sum(yk.reshape(t, cfg.top_k, d), axis=1)
         return out.reshape(b_loc, s_loc, d).astype(x_loc.dtype), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(x_spec, P(None, None), w_fsdp_in, w_fsdp_in, w_fsdp_out),

@@ -63,8 +63,6 @@ def mesh_for_shard(shard: Any) -> "jax.sharding.Mesh":
     on its fingerprint. On a 1-device host, force more CPU devices with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before the first
     jax import."""
-    from repro.utils.compat import make_mesh
-
     n_avail = len(jax.devices())
     if shard.num_devices > n_avail:
         raise ValueError(
@@ -73,7 +71,10 @@ def mesh_for_shard(shard: Any) -> "jax.sharding.Mesh":
             f"XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{shard.num_devices} before the first jax import"
         )
-    return make_mesh((shard.num_devices,), (shard.axis,))
+    return jax.make_mesh(
+        (shard.num_devices,), (shard.axis,),
+        axis_types=(jax.sharding.AxisType.Auto,),
+    )
 
 
 def mesh_fingerprint(mesh: Any) -> str:
@@ -643,7 +644,9 @@ class TuckerPlan:
                     # donate_argnames=("factors",): parameters 2..2+ndim-1.
                     donated = tuple(range(2, 2 + ndim))
             with _obs_span("plan.compile", kind=kind):
-                text = lowered.compile().as_text()
+                compiled = lowered.compile()
+                text = compiled.as_text()
+        mem = compiled.memory_analysis()
         meta = {
             "kind": kind,
             "ndim": ndim,
@@ -653,6 +656,12 @@ class TuckerPlan:
             "sharded": spec.shard is not None,
             "engine": eng.name,
             "working_dtype": str(jnp.dtype(work_dtype)),
+            # the compiler's own per-device byte counts (None where the
+            # backend reports none)
+            "temp_bytes": None if mem is None else int(mem.temp_size_in_bytes),
+            "argument_bytes": (
+                None if mem is None else int(mem.argument_size_in_bytes)
+            ),
         }
         return text, meta
 
@@ -895,6 +904,15 @@ class TuckerPlan:
                     shape=spec.shape, ranks=spec.ranks, method=spec.method,
                     n_iter=segment_len, resumable=True,
                 )
+            # the segment outputs come back replicated over the mesh; commit
+            # the first segment's carry the same way, so every segment (the
+            # first after a resume included) hits one compiled program.
+            replicated = jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec()
+            )
+            factors, core, prev_err_d, done_d, n_done_d = jax.device_put(
+                (factors, core, prev_err_d, done_d, n_done_d), replicated
+            )
 
             def dispatch() -> Any:
                 out = self._sharded_segment_program(

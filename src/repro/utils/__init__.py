@@ -1,4 +1,3 @@
-from repro.utils.compat import make_mesh
 from repro.utils.tree import (
     tree_bytes,
     tree_count,

@@ -10,9 +10,10 @@ import pytest
 
 @pytest.fixture(scope="session")
 def mesh1():
-    from repro.utils.compat import make_mesh
+    import jax
+    from jax.sharding import AxisType
 
-    return make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 @pytest.fixture(scope="session")

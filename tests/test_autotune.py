@@ -19,11 +19,8 @@ import numpy as np
 import pytest
 
 from repro import tucker
-from repro.core import engine as E
 from repro.kernels import autotune as at
 from repro.sparse.generators import random_sparse_tensor
-
-HAVE_PALLAS = "pallas" in E.available_engines()
 
 
 @pytest.fixture(autouse=True)
@@ -188,7 +185,24 @@ def test_autotune_survives_crashing_trials(tmp_path, monkeypatch):
     assert cfg == at.DEFAULT_CONFIG  # crashes lose, never propagate
 
 
-@pytest.mark.skipif(not HAVE_PALLAS, reason="pallas unavailable")
+def test_autotune_trial_errors_propagate_on_tpu(tmp_path, monkeypatch):
+    """On the chip the VMEM model has already pruned what cannot fit, so a
+    failing trial is a compiler refusal: it must raise, not silently persist
+    the default config with no timing."""
+    import jax
+
+    def boom(cfg, *a, **kw):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(at, "trial_time_ms", boom)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    table = at.TuningTable(str(tmp_path / "t.json"))
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        at.autotune((20, 16, 12), (3, 3, 2), 300, table=table,
+                    max_trials=2, backend="tpu")
+    assert not (tmp_path / "t.json").exists()  # nothing persisted
+
+
 def test_autotune_real_trial_smoke(tmp_path):
     """One REAL timed trial end-to-end (no monkeypatch): the trial path must
     compile and run a sweep under the candidate's blocks."""
@@ -206,7 +220,6 @@ def test_autotune_real_trial_smoke(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.skipif(not HAVE_PALLAS, reason="pallas unavailable")
 def test_plan_autotune_cold_then_warm_zero_search(tmp_path, monkeypatch):
     """The tentpole counter assertion: first plan searches once; a fresh
     plan on the same problem is a pure table hit — zero searches, zero
@@ -238,7 +251,6 @@ def test_plan_autotune_cold_then_warm_zero_search(tmp_path, monkeypatch):
     )
 
 
-@pytest.mark.skipif(not HAVE_PALLAS, reason="pallas unavailable")
 def test_plan_autotune_applies_blocks_to_engine(tmp_path, monkeypatch):
     monkeypatch.setenv(at.TABLE_ENV, str(tmp_path / "tab.json"))
     cands = at.candidate_configs((20, 16, 12), (3, 3, 2), 200)[:2]

@@ -34,8 +34,8 @@ def test_distributed_hooi_shim_matches_single_device():
     got = _run("""
         import warnings
         import jax, numpy as np, jax.numpy as jnp
-        from repro.utils.compat import make_mesh
-        mesh = make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         from repro.sparse.generators import low_rank_sparse_tensor
         from repro import tucker
         from repro.core.distributed import hooi_sparse_distributed
@@ -68,8 +68,8 @@ def test_train_step_shards_on_multi_device():
         from repro.models.sharding import RULES_TRAIN
         from repro.train.step import make_train_step, train_state_specs
         from repro.optim import adamw
-        from repro.utils.compat import make_mesh
-        mesh = make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         cfg = get_config("yi-6b", smoke=True)
         params = M.init_params(cfg, jax.random.PRNGKey(0))
         pshard = M.param_shardings(cfg, RULES_TRAIN, mesh)
@@ -92,8 +92,8 @@ def test_moe_ep_all_to_all_multi_device():
         from repro.models import model as M
         from repro.models.moe import moe_block
         from repro.models.sharding import DEFAULT_RULES
-        from repro.utils.compat import make_mesh
-        mesh = make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         cfg = dataclasses.replace(get_config("granite-moe-1b-a400m", smoke=True),
                                   capacity_factor=8.0, dtype="float32")
         params = M.init_params(cfg, jax.random.PRNGKey(0))
@@ -102,7 +102,7 @@ def test_moe_ep_all_to_all_multi_device():
         y, aux = jax.jit(lambda x: moe_block(cfg, mesh, DEFAULT_RULES, x,
             p["router"], p["moe_wi"], p["moe_wg"], p["moe_wo"]))(x)
         # single-device reference
-        mesh1 = make_mesh((1, 1), ("data", "model"))
+        mesh1 = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         y1, _ = moe_block(cfg, mesh1, DEFAULT_RULES, x,
             p["router"], p["moe_wi"], p["moe_wg"], p["moe_wo"])
         print(float(np.abs(np.asarray(y) - np.asarray(y1)).max()))
@@ -123,8 +123,8 @@ def test_checkpoint_elastic_reshard_across_meshes():
         mgr = CheckpointManager(d)
         mgr.save(3, params)
         # restore onto a (4,2) mesh with full shardings
-        from repro.utils.compat import make_mesh
-        mesh = make_mesh((4, 2), ("data", "model"))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         shard = M.param_shardings(cfg, RULES_TRAIN, mesh)
         restored, step, _ = mgr.restore(params, shardings=shard)
         ok = all(np.allclose(np.asarray(a, np.float32), np.asarray(b, np.float32))

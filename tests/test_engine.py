@@ -161,17 +161,6 @@ def test_unknown_engine_raises():
         E.resolve_engine("fpga")
 
 
-def test_pallas_fallback_warns(monkeypatch):
-    """pallas requested but unavailable -> warn + xla result (CPU-safe)."""
-    monkeypatch.setattr(E, "pallas_available", lambda: False)
-    coo = random_sparse_tensor((15, 12, 10), 0.05, seed=6)
-    with pytest.warns(RuntimeWarning, match="falling back"):
-        res = hooi_sparse(coo, (3, 3, 2), n_iter=1, method="gram", engine="pallas")
-    assert res.engine == "xla"
-    ref = hooi_sparse(coo, (3, 3, 2), n_iter=1, method="gram", engine="xla")
-    np.testing.assert_allclose(float(res.rel_error), float(ref.rel_error), atol=1e-6)
-
-
 # ---------------------------------------------------------------------------
 # Engine internals: layout cache, core TTM dispatch, layout invariants.
 # ---------------------------------------------------------------------------

@@ -50,7 +50,7 @@ def test_scatter_kernel(n_rows, nnz):
     plan = build_scatter_plan(rows, n_rows, bn=32, bi=32)
     contrib_perm = contrib[plan.order] * plan.valid[:, None]
     got = np.asarray(
-        scatter_rows_pallas(jnp.asarray(contrib_perm), plan, n_rows)
+        scatter_rows_pallas(jnp.asarray(contrib_perm), plan, n_rows, interpret=True)
     )
     want = np.asarray(ref.scatter_rows_ref(jnp.asarray(contrib), jnp.asarray(rows), n_rows))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)

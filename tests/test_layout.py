@@ -249,9 +249,11 @@ def ragged_coo_batches(draw, k=3, max_nnz=24):
              for _ in range(nnz)],
             dtype=np.int32,
         ).reshape(nnz, len(_PARITY_SHAPE))
-        # bounded away from 0 so no member is an (undefined) all-zero tensor
+        # bounded away from 0 so no member is an (undefined) all-zero tensor;
+        # a width-32 bound must be a float32 value itself
         vals = np.array(
-            [draw(st.floats(0.1, 4, allow_nan=False, width=32))
+            [draw(st.floats(float(np.float32(0.1)), 4, allow_nan=False,
+                            width=32))
              * (-1 if draw(st.booleans()) else 1) for _ in range(nnz)],
             dtype=np.float32,
         )

@@ -55,8 +55,9 @@ def test_bf16_master_fp32_roundtrip():
 def test_zero_spec_adds_fsdp_axis():
     # 1 CPU device: a (1,1) mesh exercises the spec logic (axis size 1
     # always divides); multi-device behaviour is covered in test_distributed.
-    from repro.utils.compat import make_mesh
-    mesh = make_mesh((1, 1), ("data", "model"))
+    import jax
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     from repro.models.sharding import ShardingRules
     rules = ShardingRules().replace(fsdp=("data",))
     s = adamw.zero_spec(P(None, "model"), (64, 32), mesh, rules)

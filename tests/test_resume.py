@@ -26,13 +26,7 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.utils.compat import has_shard_map
-
 ROOT = os.path.join(os.path.dirname(__file__), "..")
-
-needs_shard_map = pytest.mark.skipif(
-    not has_shard_map(), reason="this jax install has no shard_map"
-)
 
 SHAPE, RANKS, N_ITER, EVERY = (14, 12, 10), (3, 2, 2), 12, 5
 KILL_AT = 5  # a segment boundary: the step-5 snapshot exists when it fires
@@ -476,7 +470,6 @@ def elastic(tmp_path_factory):
     return {"a": a, "b": b}
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_sharded_kill_resume_same_device_count(elastic):
     a = elastic["a"]
@@ -493,7 +486,6 @@ def test_sharded_kill_resume_same_device_count(elastic):
     assert r["dispatches"] == 2  # sweeps 5..10, 10..12
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_sharded_resume_on_fewer_devices(elastic):
     """The elastic gate: a job snapshotted by a 4-device mesh finishes on 2

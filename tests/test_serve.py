@@ -10,8 +10,8 @@ from repro.serve.engine import Engine, ServeConfig
 
 @pytest.fixture(scope="module")
 def engine():
-    from repro.utils.compat import make_mesh
-    mesh = make_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     cfg = get_config("repro-100m", smoke=True)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     return Engine(cfg, mesh, params, ServeConfig(max_seq_len=64, batch_size=2))

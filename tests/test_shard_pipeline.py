@@ -10,8 +10,7 @@ retraces when only nnz values change, plan-cache hit on an identical mesh.
 Multi-device coverage runs in ONE subprocess under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4`` (the main test process
 keeps the real 1-device backend); the whole differential matrix is computed
-there once and asserted here from its JSON report. Skips gracefully when the
-installed jax has no shard_map spelling (see ``repro.utils.compat``).
+there once and asserted here from its JSON report.
 """
 import json
 import os
@@ -22,13 +21,7 @@ import textwrap
 import numpy as np
 import pytest
 
-from repro.utils.compat import has_shard_map
-
 ROOT = os.path.join(os.path.dirname(__file__), "..")
-
-needs_shard_map = pytest.mark.skipif(
-    not has_shard_map(), reason="this jax install has no shard_map"
-)
 
 DEVICE_COUNTS = (1, 2, 4)
 METHODS = ("svd", "gram")
@@ -148,13 +141,11 @@ def matrix():
     return json.loads(out.stdout)
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_forced_host_device_count(matrix):
     assert matrix["n_devices"] == 4
 
 
-@needs_shard_map
 @pytest.mark.slow
 @pytest.mark.parametrize("devices", DEVICE_COUNTS)
 @pytest.mark.parametrize("method", METHODS)
@@ -169,7 +160,6 @@ def test_sharded_matches_single_device(matrix, devices, method):
     assert case["n_sweeps"] == 3
 
 
-@needs_shard_map
 @pytest.mark.slow
 @pytest.mark.parametrize("devices", DEVICE_COUNTS)
 def test_sharded_single_dispatch_and_counters(matrix, devices):
@@ -192,13 +182,11 @@ def test_sharded_single_dispatch_and_counters(matrix, devices):
             assert 0.0 < c["shard_imbalance"] < 0.2
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_replan_identical_mesh_is_cache_hit(matrix):
     assert all(c["cache_hit_on_replan"] for c in matrix["cases"])
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_no_retrace_when_only_values_change(matrix):
     """Same indices, new values: zero new traces, one dispatch — and the
@@ -209,7 +197,6 @@ def test_no_retrace_when_only_values_change(matrix):
     assert vc["core_scaling_maxdiff"] < 5e-4
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_bucket_padded_calls_share_program_with_honest_imbalance(matrix):
     """pad_nnz_to stabilizes the shard_map program shape across mixed-nnz
@@ -224,7 +211,6 @@ def test_bucket_padded_calls_share_program_with_honest_imbalance(matrix):
     assert bp["imbalance_r1"] == 1.0
 
 
-@needs_shard_map
 @pytest.mark.slow
 def test_tol_early_exit_parity_sharded(matrix):
     t = matrix["tol"]
@@ -272,10 +258,11 @@ def test_shard_nonzeros_rejects_unknown_axis():
     ValueError up front, not an opaque KeyError deep in device_put."""
     from repro.core.distributed import shard_nonzeros
     from repro.sparse.generators import random_sparse_tensor
-    from repro.utils.compat import make_mesh
+    import jax
+    from jax.sharding import AxisType
 
     coo = random_sparse_tensor((6, 5, 4), 0.2, seed=0)
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     with pytest.raises(ValueError, match="bogus.*not mesh axes|not mesh axes"):
         shard_nonzeros(coo, mesh, ("bogus",))
     with pytest.raises(ValueError, match="at least one"):
@@ -299,13 +286,14 @@ def test_mesh_for_shard_capacity_error_names_the_recipe():
 
 def test_mesh_fingerprint_distinguishes_layouts():
     from repro import tucker
-    from repro.utils.compat import make_mesh
+    import jax
+    from jax.sharding import AxisType
 
-    m1 = make_mesh((1,), ("nnz",))
-    m2 = make_mesh((1,), ("data",))
+    m1 = jax.make_mesh((1,), ("nnz",), axis_types=(AxisType.Auto,))
+    m2 = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     assert tucker.mesh_fingerprint(m1) != tucker.mesh_fingerprint(m2)
     assert tucker.mesh_fingerprint(m1) == tucker.mesh_fingerprint(
-        make_mesh((1,), ("nnz",))
+        jax.make_mesh((1,), ("nnz",), axis_types=(AxisType.Auto,))
     )
 
 
@@ -328,17 +316,17 @@ def test_build_shard_schedule_target_keeps_real_nnz():
     nonzeros in the schedule's counters."""
     from repro.sparse.generators import random_sparse_tensor
     from repro.sparse.layout import build_shard_schedule
-    from repro.utils.compat import make_mesh
+    import jax
+    from jax.sharding import AxisType
 
     coo = random_sparse_tensor((6, 5, 4), 0.2, seed=1)
-    mesh = make_mesh((1,), ("nnz",))
+    mesh = jax.make_mesh((1,), ("nnz",), axis_types=(AxisType.Auto,))
     sched = build_shard_schedule(coo, mesh, ("nnz",), target_nnz=64)
     assert sched.nnz == coo.nnz  # real, not the padded 64
     assert sched.nnz_padded == 64
     assert int(sched.shard_counts.sum()) == coo.nnz
 
 
-@needs_shard_map
 def test_sharded_plan_single_device_inprocess():
     """ShardSpec(num_devices=1) runs in the main process (a 1-device mesh is
     still the full shard_map program) and matches the plain pipeline."""

@@ -1,0 +1,138 @@
+"""Compile the main path's Pallas kernels for a TPU v5e, without the chip.
+
+The TPU compiler is installed next to JAX, and it compiles for a chip that is
+described rather than attached (``jax.experimental.topologies``). Interpret
+mode, which every other kernel test runs in, cannot see what Mosaic refuses:
+unsupported vector shape casts, misaligned blocks, too much VMEM, a program
+that does not fit HBM. These tests compile the kernels at real widths (R = 16,
+128-row blocks, nell-2's 12,092-row mode) with ``interpret=False``, at both
+precisions, plus one whole Pallas sweep program at 2^18 nonzeros of nell-2's
+shape.
+
+The topology is described inside a module fixture, never at import: only one
+process may hold libtpu, and under several test workers only the worker that
+runs this file may load it.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import hooi
+from repro.core.coo import SparseCOO
+from repro.kernels import kron_kernel, ttm_kernel
+from repro.sparse.layout import DeviceSchedule, build_mode_layout
+
+R, BN, BI, ROWS = 16, 128, 128, 12_092
+NBLK = 1024  # nnz blocks streamed by one kernel call
+NELL2 = (12_092, 9_184, 28_818)
+HBM_BYTES = 16 * 2**30  # one v5e chip
+PRECISIONS = ("fp32", "bf16_fp32acc")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache off meanwhile.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _lower(kernel, precision, s):
+    """Lower one kernel call at real widths for the described chip."""
+    nnz = NBLK * BN
+    blk = _sds(s, (NBLK,), jnp.int32)
+    rows = _sds(s, (nnz, R))
+    vals = _sds(s, (nnz,))
+    rel = _sds(s, (nnz,), jnp.int32)
+    if kernel == "fused":
+        return kron_kernel._fused_call.lower(
+            blk, blk, rows, rows, vals, rel, n_rows=ROWS, bn=BN, bi=BI,
+            interpret=False, precision=precision,
+        )
+    if kernel == "mega":
+        return kron_kernel._mega_call.lower(
+            blk, blk, blk, rows, rows, vals, rel, _sds(s, (ROWS, R)),
+            n_rows=ROWS, bn=BN, bi=BI, interpret=False, precision=precision,
+        )
+    if kernel == "scatter":
+        # the unfused pair of the order >= 4 path: Kron rows at the given
+        # precision (always emitted in f32), then the one-hot scatter.
+        def chain(blkmap, first, a, b, v, rel_row):
+            contrib = kron_kernel.kron_contrib_pallas(
+                a, b, v, interpret=False, precision=precision
+            )
+            return kron_kernel._scatter_call(
+                blkmap, first, rel_row, contrib, n_rows=ROWS, bn=BN, bi=BI,
+                interpret=False,
+            )
+
+        return jax.jit(chain).lower(blk, blk, rows, rows, vals, rel)
+    assert kernel == "ttm"
+    return ttm_kernel.ttm_pallas.lower(
+        _sds(s, (R * R, ROWS)), _sds(s, (R, ROWS)), interpret=False,
+        precision=precision,
+    )
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+@pytest.mark.parametrize("kernel", ["fused", "mega", "scatter", "ttm"])
+def test_kernel_compiles_for_v5e(one_chip, kernel, precision):
+    text = _lower(kernel, precision, one_chip).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _nell2_coo(nnz, seed=0):
+    rng = np.random.default_rng(seed)
+    total = int(np.prod(NELL2, dtype=np.int64))
+    lin = rng.permutation(np.unique(rng.integers(0, total, size=nnz + 4096)))
+    idx = np.stack(np.unravel_index(lin[:nnz], NELL2), axis=1)
+    return SparseCOO.from_parts(idx, np.ones((nnz,), np.float32), NELL2)
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+def test_scan_program_compiles_for_v5e(one_chip, precision):
+    """The whole 5-sweep Pallas program of a nell-2 plan at 2^18 nonzeros:
+    Mosaic kernels inside, and it fits one chip's HBM."""
+    coo = _nell2_coo(2**18)
+    ranks = (R, R, R)
+
+    def spec_of(x):
+        return _sds(one_chip, x.shape, x.dtype)
+
+    scheds = tuple(
+        jax.tree.map(spec_of, DeviceSchedule.from_layout(build_mode_layout(coo, m)))
+        for m in range(3)
+    )
+    factors = tuple(_sds(one_chip, (NELL2[m], R)) for m in range(3))
+    scalar = _sds(one_chip, ())
+    compiled = hooi._scan_sweeps.lower(
+        spec_of(coo.indices), spec_of(coo.values), factors, scalar, scalar,
+        scheds, shape=NELL2, ranks=ranks, method="householder", n_iter=5,
+        engine_name="pallas", interpret=False, use_reuse=False,
+        precision=precision,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES

@@ -196,7 +196,10 @@ def test_batch_matches_sequential_xla():
     assert hooi.SWEEP_DISPATCH_COUNTS[("xla", "scan")] - d0 == 1  # ONE dispatch
     assert len(got) == len(seq)
     for g, s in zip(got, seq):
-        np.testing.assert_array_equal(g.fit_history, s.fit_history)
+        # the vmapped and the sequential programs reduce in a different
+        # order, so the fit may differ in its last bit
+        np.testing.assert_allclose(g.fit_history, s.fit_history,
+                                   rtol=1e-6, atol=1e-7)
         np.testing.assert_allclose(
             np.asarray(g.core), np.asarray(s.core), rtol=1e-5, atol=1e-5
         )

@@ -392,10 +392,6 @@ def test_service_over_mesh_plans_sharded():
     submitted spec without its own shard plans sharded (one shard_map
     dispatch per request) — and the no-amortization warning stays silent,
     because sequential flushes are the sharded design, not a fallback."""
-    from repro.utils.compat import has_shard_map
-
-    if not has_shard_map():
-        pytest.skip("this jax install has no shard_map")
     shard = tucker.ShardSpec(num_devices=1)  # a 1-device mesh is still the
     coos = _coos(2, seed0=500)               # full shard_map program
     cfg = ServiceConfig(max_batch=2, max_wait_ms=10_000.0, shard=shard)
@@ -423,10 +419,7 @@ def test_service_sharded_flushes_bucket_pad_no_retrace():
     shard_map program: the flush pads members to the bucket (then the even
     shard multiple), so only the first flush of a bucket traces."""
     from repro.core import hooi
-    from repro.utils.compat import has_shard_map
 
-    if not has_shard_map():
-        pytest.skip("this jax install has no shard_map")
     shard = tucker.ShardSpec(num_devices=1)
     spec = tucker.TuckerSpec(shape=(13, 11, 9), ranks=(2, 2, 2),
                              method="gram", n_iter=2)
