@@ -16,10 +16,6 @@ def main() -> None:
     ap.add_argument("--only", default="", help="comma list of sections")
     args = ap.parse_args()
 
-    from repro.utils.compile_cache import enable_compile_cache
-
-    enable_compile_cache()
-
     from benchmarks import (
         fig6_sparsity, sweep_bench, table2_accuracy, table3_ttm, table4_kron,
         table5_realworld,

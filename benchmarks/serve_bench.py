@@ -382,11 +382,11 @@ def main(argv: Optional[list] = None) -> int:
     import jax
 
     from benchmarks.common import registry_snapshot
+    from repro import tucker
+    from repro.sparse.layout import bucket_nnz
     from repro.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    from repro import tucker
-    from repro.sparse.layout import bucket_nnz
 
     if args.slo:
         slo_section, failures = run_slo_phase(args.smoke, args.trace_out)

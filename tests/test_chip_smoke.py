@@ -1,10 +1,14 @@
 """``chip_smoke.py`` must refuse to run anywhere but on a TPU: without one it
-exits nonzero before any phase and prints no verdict line."""
+exits nonzero before any phase and prints no verdict line. The compile cache
+its entry points enable lives where ``$JAX_COMPILATION_CACHE_DIR`` says, else
+at the fixed ``<repo>/.jax_cache``."""
 import os
 import subprocess
 import sys
 
-ROOT = os.path.join(os.path.dirname(__file__), "..")
+import pytest
+
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 
 def test_chip_smoke_refuses_cpu():
@@ -16,3 +20,24 @@ def test_chip_smoke_refuses_cpu():
     assert out.returncode != 0
     assert '"ok"' not in out.stdout
     assert "needs a TPU" in out.stderr
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_dir(tmp_path, from_env):
+    # a child process, so this process's JAX config stays untouched
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(ROOT, ".jax_cache")
+    if from_env:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\n"
+         "from repro.utils.compile_cache import enable_compile_cache\n"
+         "print(enable_compile_cache())\n"
+         "print(jax.config.jax_compilation_cache_dir)\n"],
+        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split() == [want, want]
