@@ -27,6 +27,7 @@ from typing import Dict, List, Optional, Sequence
 
 import jax
 
+from repro.core import stages
 from repro.core.coo import SparseCOO
 from repro.obs import registry as _obs_registry, span as _obs_span
 from repro.sparse.layout import (
@@ -275,16 +276,17 @@ class SweepEngine:
     # -- Alg. 2 line 9: core from the last unfolding (module 1) -----------
     def core_unfolding(self, y_n: jax.Array, u_last: jax.Array) -> jax.Array:
         """G_(N) = U_N^T Y_(N) (Eq. 12): (R_N, prod_{t != N} R_t)."""
-        if self.name == "pallas":
-            from repro.kernels import ops
+        with jax.named_scope(stages.CORE):
+            if self.name == "pallas":
+                from repro.kernels import ops
 
-            return ops.ttm(
-                y_n.T, u_last.T, bl=self.bl, bk=self.bk,
-                interpret=self.interpret, precision=self.precision,
-            ).T
-        from repro.core.ttm import ttm_unfolded
+                return ops.ttm(
+                    y_n.T, u_last.T, bl=self.bl, bk=self.bk,
+                    interpret=self.interpret, precision=self.precision,
+                ).T
+            from repro.core.ttm import ttm_unfolded
 
-        return ttm_unfolded(y_n.T, u_last.T).T
+            return ttm_unfolded(y_n.T, u_last.T).T
 
     def core_update(
         self, coo: SparseCOO, factors: Sequence[jax.Array], y_n: jax.Array

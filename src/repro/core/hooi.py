@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import stages
 from repro.core.coo import SparseCOO, fold_dense
 from repro.core.engine import SweepEngine
 from repro.core.kron import (
@@ -131,17 +132,18 @@ def init_factors(
     ``dtype=None`` follows the jax x64 flag (the legacy behavior)."""
     if dtype is None:
         dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
-    keys = jax.random.split(key, len(shape))
     factors = []
-    for k, (i, r) in zip(keys, zip(shape, ranks)):
-        u = jax.random.normal(k, (i, r), dtype=dtype)
-        if orthonormal:
-            # lapack has no half-precision QR: orthonormalize at >= f32 and
-            # cast back to the working dtype.
-            qdt = jnp.promote_types(dtype, jnp.float32)
-            q, _ = jnp.linalg.qr(u.astype(qdt))
-            u = q.astype(dtype)
-        factors.append(u)
+    with jax.named_scope(stages.INIT):
+        keys = jax.random.split(key, len(shape))
+        for k, (i, r) in zip(keys, zip(shape, ranks)):
+            u = jax.random.normal(k, (i, r), dtype=dtype)
+            if orthonormal:
+                # lapack has no half-precision QR: orthonormalize at >= f32
+                # and cast back to the working dtype.
+                qdt = jnp.promote_types(dtype, jnp.float32)
+                q, _ = jnp.linalg.qr(u.astype(qdt))
+                u = q.astype(dtype)
+            factors.append(u)
     return factors
 
 
@@ -237,8 +239,10 @@ def sparse_sweep(
     if engine is not None:
         g_n = engine.core_update(coo, factors, y_n)  # (R_N, prod R_t)
     else:
-        g_n = ttm_unfolded(y_n.T, factors[n - 1].T).T  # (R_N, prod R_t)
-    core = fold_dense(g_n, n - 1, list(ranks))
+        with jax.named_scope(stages.CORE):
+            g_n = ttm_unfolded(y_n.T, factors[n - 1].T).T  # (R_N, prod R_t)
+    with jax.named_scope(stages.CORE):
+        core = fold_dense(g_n, n - 1, list(ranks))
     return factors, core
 
 
@@ -308,14 +312,16 @@ def _sweep_scan(
         # changed since y_n was built) — the fused megakernel re-gathers
         # from it, the split path contracts y_n against fs[n-1] directly.
         g_n = core_unfolding(fs, y_n)
-        core = fold_dense(g_n, n - 1, list(ranks)).astype(core_dtype)
-        err = (
-            jnp.sqrt(jnp.maximum(xnorm2 - jnp.sum(jnp.square(core)), 0.0))
-            / jnp.sqrt(xnorm2)
-        ).astype(jnp.float32)
-        # same rule as the legacy loop: stop once two consecutive sweeps agree
-        # to within tol (never on the first sweep — prev_err starts at +inf).
-        done = (tol > 0) & jnp.isfinite(prev_err) & (jnp.abs(prev_err - err) < tol)
+        with jax.named_scope(stages.CORE):
+            core = fold_dense(g_n, n - 1, list(ranks)).astype(core_dtype)
+            err = (
+                jnp.sqrt(jnp.maximum(xnorm2 - jnp.sum(jnp.square(core)), 0.0))
+                / jnp.sqrt(xnorm2)
+            ).astype(jnp.float32)
+            # same rule as the legacy loop: stop once two consecutive sweeps
+            # agree to within tol (never on the first sweep — prev_err
+            # starts at +inf).
+            done = (tol > 0) & jnp.isfinite(prev_err) & (jnp.abs(prev_err - err) < tol)
         return tuple(fs), core, err, done, n_done + jnp.int32(1)
 
     def body(carry, _):
@@ -389,11 +395,13 @@ def _engine_unfoldings(
                     indices, values, fs, n - 1, scheds[n - 1],
                     shape=shape, interpret=interpret, precision=precision,
                 )
-            return ops.ttm(
-                y_n.T, fs[n - 1].T, bl=bl, bk=bk, interpret=interpret,
-                precision=precision,
-            ).T
-        return ttm_unfolded(y_n.T, fs[n - 1].T).T
+            with jax.named_scope(stages.CORE):
+                return ops.ttm(
+                    y_n.T, fs[n - 1].T, bl=bl, bk=bk, interpret=interpret,
+                    precision=precision,
+                ).T
+        with jax.named_scope(stages.CORE):
+            return ttm_unfolded(y_n.T, fs[n - 1].T).T
 
     return mode_unfolding, core_unfolding
 
@@ -527,7 +535,8 @@ def _batched_scan_sweeps(
         # identical formula to the per-tensor path (square of the norm); the
         # vmapped program still reduces in its own order, so batched results
         # match sequential calls to the last bit or so, not bitwise.
-        xn = jnp.square(jnp.sqrt(jnp.sum(jnp.square(val.astype(jnp.float32)))))
+        with jax.named_scope(stages.INIT):
+            xn = jnp.square(jnp.sqrt(jnp.sum(jnp.square(val.astype(jnp.float32)))))
         return _scan_sweeps_impl(
             idx, val, fs, xn, tol, None,
             shape=shape, ranks=ranks, method=method, n_iter=n_iter,
@@ -592,10 +601,12 @@ def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
             partial_y = sparse_ttm_chain(
                 SparseCOO(indices, values, shape), fs, mode
             )
-            return jax.lax.psum(partial_y, nnz_axes)
+            with jax.named_scope(stages.PSUM):
+                return jax.lax.psum(partial_y, nnz_axes)
 
         def core_unfolding(fs, y_n):
-            return ttm_unfolded(y_n.T, fs[-1].T).T
+            with jax.named_scope(stages.CORE):
+                return ttm_unfolded(y_n.T, fs[-1].T).T
 
         return mode_unfolding, core_unfolding
 

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import stages
 from repro.core.coo import SparseCOO
 from repro.sparse.layout import KronReusePlan, build_kron_reuse
 
@@ -96,20 +97,22 @@ def sparse_ttm_chain(
     """
     if coo.indices.shape[0] == 0:
         return zero_unfolding(coo.shape, factors, skip_mode)
-    rows = gathered_factor_rows(coo, factors, skip_mode)
-    if precision == "bf16_fp32acc":
-        rows = [r.astype(jnp.bfloat16) for r in rows]
-        k = kron_rows(rows)  # (nnz, K) bf16 multiplies
-        dt = jnp.promote_types(coo.values.dtype, jnp.float32)
-    else:
-        k = kron_rows(rows)  # (nnz, K)
-        dt = jnp.promote_types(
-            jnp.promote_types(coo.values.dtype, k.dtype), jnp.float32
-        )
-    contrib = k.astype(dt) * coo.values.astype(dt)[:, None]
-    i_n = coo.indices[:, skip_mode]
-    out = jnp.zeros((coo.shape[skip_mode], k.shape[1]), dtype=dt)
-    return out.at[i_n].add(contrib)
+    with jax.named_scope(stages.ROW_GATHER):
+        rows = gathered_factor_rows(coo, factors, skip_mode)
+    with jax.named_scope(stages.KRON):
+        if precision == "bf16_fp32acc":
+            rows = [r.astype(jnp.bfloat16) for r in rows]
+            k = kron_rows(rows)  # (nnz, K) bf16 multiplies
+            dt = jnp.promote_types(coo.values.dtype, jnp.float32)
+        else:
+            k = kron_rows(rows)  # (nnz, K)
+            dt = jnp.promote_types(
+                jnp.promote_types(coo.values.dtype, k.dtype), jnp.float32
+            )
+        contrib = k.astype(dt) * coo.values.astype(dt)[:, None]
+        i_n = coo.indices[:, skip_mode]
+        out = jnp.zeros((coo.shape[skip_mode], k.shape[1]), dtype=dt)
+        return out.at[i_n].add(contrib)
 
 
 def precompute_kron_reuse(coo: SparseCOO, skip_mode: int) -> KronReusePlan:
@@ -135,14 +138,16 @@ def _reuse_chain(
     (DeviceSchedule) — the single implementation behind both entry points."""
     if indices.shape[0] == 0:
         return zero_unfolding(tuple(shape), factors, skip_mode)
-    rows = [factors[t][unique_indices[:, c]] for c, t in enumerate(modes)]
-    k_unique = kron_rows(rows)  # (n_unique, K)
-    k = k_unique[inverse]  # (nnz, K)
-    dt = jnp.promote_types(jnp.promote_types(values.dtype, k.dtype), jnp.float32)
-    contrib = k.astype(dt) * values.astype(dt)[:, None]
-    i_n = indices[:, skip_mode]
-    out = jnp.zeros((shape[skip_mode], k.shape[1]), dtype=dt)
-    return out.at[i_n].add(contrib)
+    with jax.named_scope(stages.ROW_GATHER):
+        rows = [factors[t][unique_indices[:, c]] for c, t in enumerate(modes)]
+    with jax.named_scope(stages.KRON):
+        k_unique = kron_rows(rows)  # (n_unique, K)
+        k = k_unique[inverse]  # (nnz, K)
+        dt = jnp.promote_types(jnp.promote_types(values.dtype, k.dtype), jnp.float32)
+        contrib = k.astype(dt) * values.astype(dt)[:, None]
+        i_n = indices[:, skip_mode]
+        out = jnp.zeros((shape[skip_mode], k.shape[1]), dtype=dt)
+        return out.at[i_n].add(contrib)
 
 
 def sparse_ttm_chain_reuse(

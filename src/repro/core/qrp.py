@@ -27,6 +27,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import stages
+
 _EPS = 1e-12
 
 
@@ -200,9 +202,10 @@ def factor_update(y_n: jax.Array, r: int, method: str) -> jax.Array:
     (``fori_loop`` chains, no data-dependent Python), which is what lets the
     whole-sweep pipeline in ``core.hooi`` run N of these inside one compiled
     ``lax.scan`` over sweeps."""
-    if method == "svd":
-        return svd_factor(y_n, r)
-    return qrp(y_n, r, method=method)
+    with jax.named_scope(stages.QRP):
+        if method == "svd":
+            return svd_factor(y_n, r)
+        return qrp(y_n, r, method=method)
 
 
 def qrp_flops(m: int, n: int) -> int:

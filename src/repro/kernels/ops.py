@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import stages
 from repro.core.coo import SparseCOO
 from repro.kernels import kron_kernel, ttm_kernel
 from repro.kernels.kron_kernel import ScatterPlan, build_scatter_plan
@@ -87,12 +88,14 @@ def _gathered_block_rows(indices, values, factors, skip_mode, sched, n):
     fused core update, with identical operands on purpose: when both run in
     one program (the megakernel re-streams the same nonzeros the mode-(N-1)
     unfolding just consumed), XLA CSEs the gathers instead of re-reading."""
-    idx = indices[sched.order]
-    vals = values[sched.order] * sched.valid
+    with jax.named_scope(stages.ORDER_GATHER):
+        idx = indices[sched.order]
+        vals = values[sched.order] * sched.valid
     modes = [t for t in range(n - 1, -1, -1) if t != skip_mode]
-    rows = [factors[t][idx[:, t]] for t in modes]
-    if len(rows) == 1:  # order-2 tensor: the "Kron row" is a single factor row
-        rows.append(jnp.ones((rows[0].shape[0], 1), dtype=rows[0].dtype))
+    with jax.named_scope(stages.ROW_GATHER):
+        rows = [factors[t][idx[:, t]] for t in modes]
+        if len(rows) == 1:  # order-2 tensor: the "Kron row" is a single factor row
+            rows.append(jnp.ones((rows[0].shape[0], 1), dtype=rows[0].dtype))
     return rows, vals
 
 
@@ -121,17 +124,18 @@ def sparse_ttm_chain_device(
 
         return zero_unfolding(tuple(shape), factors, skip_mode)
     rows, vals = _gathered_block_rows(indices, values, factors, skip_mode, sched, n)
-    if len(rows) == 2 and fused:
-        return kron_kernel.fused_kron_scatter_pallas(
-            rows[0], rows[1], vals, sched, n_rows, interpret=interpret,
-            precision=precision,
+    with jax.named_scope(stages.KRON):
+        if len(rows) == 2 and fused:
+            return kron_kernel.fused_kron_scatter_pallas(
+                rows[0], rows[1], vals, sched, n_rows, interpret=interpret,
+                precision=precision,
+            )
+        contrib = kron_contrib(
+            rows[0], rows[1], vals, interpret=interpret, precision=precision
         )
-    contrib = kron_contrib(
-        rows[0], rows[1], vals, interpret=interpret, precision=precision
-    )
-    for extra in rows[2:]:
-        contrib = kron_contrib(contrib, extra, jnp.ones_like(vals), interpret=interpret)
-    return kron_kernel.scatter_rows_pallas(contrib, sched, n_rows, interpret=interpret)
+        for extra in rows[2:]:
+            contrib = kron_contrib(contrib, extra, jnp.ones_like(vals), interpret=interpret)
+        return kron_kernel.scatter_rows_pallas(contrib, sched, n_rows, interpret=interpret)
 
 
 def sparse_ttm_core_device(
@@ -166,15 +170,19 @@ def sparse_ttm_core_device(
         return jnp.zeros((u.shape[1], y0.shape[1]), dtype=jnp.float32)
     rows, vals = _gathered_block_rows(indices, values, factors, skip_mode, sched, n)
     if len(rows) == 2:
-        return kron_kernel.fused_kron_scatter_ttm_pallas(
-            rows[0], rows[1], vals, u, sched, n_rows, interpret=interpret,
-            precision=precision,
-        )
+        # the megakernel's Kron accumulation and its TTM are one kernel,
+        # counted with the Kron kernels
+        with jax.named_scope(stages.KRON):
+            return kron_kernel.fused_kron_scatter_ttm_pallas(
+                rows[0], rows[1], vals, u, sched, n_rows, interpret=interpret,
+                precision=precision,
+            )
     y = sparse_ttm_chain_device(
         indices, values, factors, skip_mode, sched,
         shape=shape, interpret=interpret, precision=precision,
     )
-    return ttm(y.T, u.T, interpret=interpret, precision=precision).T
+    with jax.named_scope(stages.CORE):
+        return ttm(y.T, u.T, interpret=interpret, precision=precision).T
 
 
 def flash_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = None,

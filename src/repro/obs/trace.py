@@ -19,7 +19,9 @@ Design constraints, in order:
 2. **Bounded.** The ring holds ``ring_capacity`` finished spans; a
    long-lived service overwrites its oldest history instead of growing.
 3. **No jax.** Importable from anywhere in the stack (including
-   ``runtime.fault_tolerance``) without dragging device runtimes in.
+   ``runtime.fault_tolerance``) without dragging device runtimes in. What
+   needs JAX (the compile-stage spans, the profiler annotations) is plugged
+   in by :mod:`repro.obs.jax_bridge`.
 
 Parentage is a thread-local span stack: a span opened while another is
 active on the same thread records it as ``parent``, which is how one served
@@ -36,7 +38,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Deque, Dict, Iterator, List, Optional
+from typing import Any, Callable, Deque, Dict, Iterator, List, Optional
 
 __all__ = ["SpanEvent", "Span", "Tracer"]
 
@@ -74,9 +76,12 @@ class Span:
     """A live span handed to the ``with`` body; finished on exit.
 
     ``set_attr`` adds attributes discovered mid-span (e.g. ``sweeps_run``
-    is only known after the dispatch returns)."""
+    is only known after the dispatch returns). While the tracer has an
+    ``annotation`` factory, the span also enters one of its name, so a
+    profiler capture shows it on the host's lanes."""
 
-    __slots__ = ("name", "span_id", "parent_id", "attrs", "_t0", "_tracer")
+    __slots__ = ("name", "span_id", "parent_id", "attrs", "_t0", "_tracer",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str,
                  parent_id: Optional[int], span_id: int,
@@ -87,17 +92,24 @@ class Span:
         self.parent_id = parent_id
         self.attrs = attrs
         self._t0 = 0.0
+        self._annotation: Any = None
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
 
     def __enter__(self) -> "Span":
         self._tracer._push(self)
+        annotation = self._tracer.annotation
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type: Any, exc: Any, tb: Any) -> None:
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         self._tracer._pop(self, t1)
@@ -138,6 +150,10 @@ class Tracer:
     def __init__(self, enabled: bool = False,
                  ring_capacity: int = DEFAULT_RING_CAPACITY) -> None:
         self.enabled = bool(enabled)
+        # a context-manager factory taking a span's name, entered around
+        # every span while tracing is on (``jax.profiler.TraceAnnotation``
+        # once repro.obs.jax_bridge is installed)
+        self.annotation: Optional[Callable[[str], Any]] = None
         self._lock = threading.Lock()
         self._ring: Deque[SpanEvent] = deque(maxlen=int(ring_capacity))
         self._ids = itertools.count(1)
@@ -185,9 +201,18 @@ class Tracer:
         if not self.enabled:
             return
         t = time.perf_counter()
+        self.record(name, t, t, **attrs)
+
+    def record(self, name: str, t0: float, t1: float, **attrs: Any) -> None:
+        """Record a span that has already finished, from ``t0`` to ``t1``
+        on ``time.perf_counter``, as a child of the span open on this
+        thread (how JAX's compile stages, reported after the fact, land
+        under the dispatch that compiled)."""
+        if not self.enabled:
+            return
         th = threading.current_thread()
         ev = SpanEvent(
-            name=name, t0=t, t1=t, span_id=next(self._ids),
+            name=name, t0=t0, t1=t1, span_id=next(self._ids),
             parent_id=self._current_id(), thread_id=th.ident or 0,
             thread_name=th.name, attrs=dict(attrs),
         )

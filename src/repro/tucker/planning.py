@@ -33,12 +33,17 @@ from repro.core import hooi as _hooi
 from repro.core.coo import SparseCOO
 from repro.core.engine import SweepEngine, resolve_engine
 from repro.obs import event as _obs_event
+from repro.obs import jax_bridge as _obs_jax_bridge
 from repro.obs import registry as _obs_registry
 from repro.obs import span as _obs_span
 from repro.obs import tracer as _obs_tracer
 from repro.sparse.layout import pad_coo_batch
 from repro.tucker.result import TuckerResult
 from repro.tucker.spec import TuckerSpec, spec_for
+
+# JAX's trace/lower/compile stages as jit.* spans and a counter, once per
+# process
+_obs_jax_bridge.install()
 
 __all__ = [
     "PlanCache",

@@ -27,6 +27,8 @@ RANKS = (3, 3, 2)
 @pytest.fixture
 def traced():
     obs.configure(enabled=True)
+    # the ring is process-wide: drop what earlier tests in this worker traced
+    obs.tracer.clear()
     try:
         yield obs.tracer
     finally:

@@ -13,7 +13,10 @@ The topology is described inside a module fixture, never at import: only one
 process may hold libtpu, and under several test workers only the worker that
 runs this file may load it.
 """
+import base64
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -136,3 +139,67 @@ def test_scan_program_compiles_for_v5e(one_chip, precision):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+def test_scan_program_names_its_stages_for_v5e(one_chip, monkeypatch):
+    """On the chip's compiler too, every gather and custom call that JAX
+    lowered in the Pallas program carries one ``tucker.*`` scope (the Kron
+    kernels ``tucker.kron``, the TTM kernel ``tucker.core``); three custom
+    calls the compiler makes itself (a buffer, two concatenations of factor
+    slices) carry no metadata. The scopes change no instruction, and no
+    kernel beyond the source locations its serialized body carries."""
+    from test_stages import STAGE, assert_one_stage_each, without_metadata
+
+    from repro.core import stages
+
+    coo = _nell2_coo(2**14)
+
+    def spec_of(x):
+        return _sds(one_chip, x.shape, x.dtype)
+
+    scheds = tuple(
+        jax.tree.map(spec_of, DeviceSchedule.from_layout(build_mode_layout(coo, m)))
+        for m in range(3)
+    )
+    factors = tuple(_sds(one_chip, (NELL2[m], R)) for m in range(3))
+    scalar = _sds(one_chip, ())
+
+    def compiled_text():
+        jax.clear_caches()
+        return hooi._scan_sweeps.lower(
+            spec_of(coo.indices), spec_of(coo.values), factors, scalar, scalar,
+            scheds, shape=NELL2, ranks=(R, R, R), method="householder", n_iter=5,
+            engine_name="pallas", interpret=False, use_reuse=False,
+        ).compile().as_text()
+
+    scoped = compiled_text()
+    assert assert_one_stage_each(scoped, compiler_made=3) == {
+        stages.ORDER_GATHER, stages.ROW_GATHER, stages.KRON, stages.QRP, stages.CORE}
+    kernels = [STAGE.findall(ln) for ln in scoped.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sorted(k for (k,) in kernels) == sorted([stages.KRON] * 3 + [stages.CORE])
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    plain = compiled_text()
+    # a Mosaic kernel's serialized body carries source locations too: compare
+    # the bodies as MLIR without debug info, the rest without metadata
+    assert mosaic_kernels(plain) == mosaic_kernels(scoped)
+    assert (KERNEL_BODY.sub("", without_metadata(plain))
+            == KERNEL_BODY.sub("", without_metadata(scoped)))
+
+
+KERNEL_BODY = re.compile(r'"body":"[^"]*"')
+
+
+def mosaic_kernels(text):
+    """Each Mosaic kernel of a compiled program as MLIR without debug info."""
+    from jax._src.lib.mlir import ir
+
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    out = []
+    with ctx:
+        for body in re.findall(r'"custom_call_config":\{"body":"([^"]*)"', text):
+            module = ir.Module.parse(base64.b64decode(body))
+            out.append(module.operation.get_asm(enable_debug_info=False))
+    assert out
+    return out
