@@ -364,14 +364,21 @@ def _engine_unfoldings(
     program so engine routing (pallas kernels, Kron-reuse dedup, plain XLA)
     cannot drift between them. ``precision``/``bl``/``bk``/``fuse_core`` are
     the autotuner-facing statics: kernel block shapes, the mixed-precision
-    axis, and the fused-megakernel core layout (pallas only)."""
+    axis, and the fused-megakernel core layout (pallas only).
+
+    Called once per program, outside ``_sweep_scan``: on the pallas engine
+    it orders the nonzeros into every mode's schedule order here, and every
+    sweep of the call reads those operands."""
+    n = len(shape)
+    if engine_name == "pallas":
+        from repro.kernels import ops
+
+        ordered = [ops.order_gather(indices, values, scheds[m], m) for m in range(n)]
 
     def mode_unfolding(fs, mode):
         if engine_name == "pallas":
-            from repro.kernels import ops
-
-            return ops.sparse_ttm_chain_device(
-                indices, values, fs, mode, scheds[mode],
+            return ops.sorted_ttm_chain(
+                ordered[mode], fs, mode, scheds[mode],
                 shape=shape, interpret=interpret, precision=precision,
             )
         if use_reuse:
@@ -383,16 +390,13 @@ def _engine_unfoldings(
         )
 
     def core_unfolding(fs, y_n):
-        n = len(shape)
         if engine_name == "pallas":
-            from repro.kernels import ops
-
             if fuse_core:
                 # megakernel: G = U^T Y with Y rebuilt in VMEM from the
-                # nonzeros — the unfolding never crosses HBM a second time
-                # (the factor-row gathers CSE with mode_unfolding's).
-                return ops.sparse_ttm_core_device(
-                    indices, values, fs, n - 1, scheds[n - 1],
+                # mode-(N-1) unfolding's own sorted operands — the
+                # unfolding never crosses HBM a second time.
+                return ops.sorted_ttm_core(
+                    ordered[n - 1], fs, n - 1, scheds[n - 1],
                     shape=shape, interpret=interpret, precision=precision,
                 )
             with jax.named_scope(stages.CORE):

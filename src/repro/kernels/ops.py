@@ -6,7 +6,7 @@ compile to Mosaic.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -82,21 +82,50 @@ def sparse_ttm_chain_kernel(
     )
 
 
-def _gathered_block_rows(indices, values, factors, skip_mode, sched, n):
-    """Gather the non-mode factor rows in the schedule's block order (padding
-    slots gather row 0 with value 0). Shared by the unfolding chain and the
-    fused core update, with identical operands on purpose: when both run in
-    one program (the megakernel re-streams the same nonzeros the mode-(N-1)
-    unfolding just consumed), XLA CSEs the gathers instead of re-reading."""
+class SortedNonzeros(NamedTuple):
+    """One mode's nonzeros in its schedule's block order: what the Kron
+    kernels stream, minus the factor rows. ``coords[i]`` is the coordinate
+    of non-mode ``_row_modes(n, skip_mode)[i]`` of each slot; padding slots
+    read coordinate 0 with value 0. Every field is 1-D, ``(P,)``: a
+    ``(P, N)`` index array would be tiled to a padded lane width on a TPU."""
+
+    values: jax.Array  # (P,) values[order] * valid
+    coords: Tuple[jax.Array, ...]  # (P,) int32 each
+
+
+def _row_modes(n: int, skip_mode: int) -> list:
+    """The non-mode modes in the order the Kron kernels take their rows."""
+    return [t for t in range(n - 1, -1, -1) if t != skip_mode]
+
+
+def order_gather(indices, values, sched, skip_mode) -> SortedNonzeros:
+    """Permute the nonzeros into the mode's schedule order (``sched`` a
+    ``ScatterPlan``, ``SortedCOO`` or ``DeviceSchedule``). It reads only the
+    tensor and the schedule, never the factors, so a compiled pipeline runs
+    it once per call before its sweeps; the per-call drivers run it at each
+    call. An empty tensor has no order and needs no schedule."""
+    modes = _row_modes(indices.shape[1], skip_mode)
+    if indices.shape[0] == 0:
+        return SortedNonzeros(values, tuple(indices[:, t] for t in modes))
     with jax.named_scope(stages.ORDER_GATHER):
+        # the (P, N) rows are gathered once, then cut into 1-D columns, so
+        # the padded temporary dies here
         idx = indices[sched.order]
-        vals = values[sched.order] * sched.valid
-    modes = [t for t in range(n - 1, -1, -1) if t != skip_mode]
+        return SortedNonzeros(values[sched.order] * sched.valid,
+                              tuple(idx[:, t] for t in modes))
+
+
+def _gathered_block_rows(nz: SortedNonzeros, factors, skip_mode):
+    """Gather each slot's non-mode factor rows from the pre-sorted
+    coordinates (padding slots read row 0). The unfolding chain and the
+    fused core update of one mode both read the same ``nz``, which the
+    compiled pipeline orders once per call; only these row gathers, which
+    read the factors of the moment, run in every sweep."""
     with jax.named_scope(stages.ROW_GATHER):
-        rows = [factors[t][idx[:, t]] for t in modes]
+        rows = [factors[t][c] for t, c in zip(_row_modes(len(factors), skip_mode), nz.coords)]
         if len(rows) == 1:  # order-2 tensor: the "Kron row" is a single factor row
             rows.append(jnp.ones((rows[0].shape[0], 1), dtype=rows[0].dtype))
-    return rows, vals
+    return rows
 
 
 def sparse_ttm_chain_device(
@@ -111,19 +140,39 @@ def sparse_ttm_chain_device(
     fused: bool = True,
     precision: str = "fp32",
 ) -> jax.Array:
-    """Trace-safe twin of :func:`sparse_ttm_chain_kernel` for the compiled
-    scan-over-sweeps pipeline: the schedule (``sched``, a
-    ``sparse.layout.DeviceSchedule``) is already device-resident, ``shape`` /
-    ``interpret`` are static, and no numpy or host sync happens — safe to
-    call under ``jit`` / ``lax.scan`` / ``lax.cond``.
+    """Trace-safe twin of :func:`sparse_ttm_chain_kernel`: the schedule
+    (``sched``, a ``sparse.layout.DeviceSchedule``) is already
+    device-resident, ``shape`` / ``interpret`` are static, and no numpy or
+    host sync happens. Orders the nonzeros at this call
+    (:func:`order_gather`), then runs :func:`sorted_ttm_chain`.
     """
-    n = len(shape)
+    return sorted_ttm_chain(
+        order_gather(indices, values, sched, skip_mode), factors, skip_mode, sched,
+        shape=shape, interpret=interpret, fused=fused, precision=precision,
+    )
+
+
+def sorted_ttm_chain(
+    nz: SortedNonzeros,
+    factors: Sequence[jax.Array],
+    skip_mode: int,
+    sched,
+    *,
+    shape: Sequence[int],
+    interpret: bool,
+    fused: bool = True,
+    precision: str = "fp32",
+) -> jax.Array:
+    """Y_(n) from nonzeros already in the mode's schedule order — what the
+    compiled scan-over-sweeps pipeline calls every sweep on operands it
+    ordered once. Safe under ``jit`` / ``lax.scan`` / ``lax.cond``."""
     n_rows = int(shape[skip_mode])
-    if indices.shape[0] == 0:
+    if nz.values.shape[0] == 0:
         from repro.core.kron import zero_unfolding
 
         return zero_unfolding(tuple(shape), factors, skip_mode)
-    rows, vals = _gathered_block_rows(indices, values, factors, skip_mode, sched, n)
+    rows = _gathered_block_rows(nz, factors, skip_mode)
+    vals = nz.values
     with jax.named_scope(stages.KRON):
         if len(rows) == 2 and fused:
             return kron_kernel.fused_kron_scatter_pallas(
@@ -149,36 +198,53 @@ def sparse_ttm_core_device(
     interpret: bool,
     precision: str = "fp32",
 ) -> jax.Array:
+    """The fused core update at one call: orders the nonzeros
+    (:func:`order_gather`), then runs :func:`sorted_ttm_core`."""
+    return sorted_ttm_core(
+        order_gather(indices, values, sched, skip_mode), factors, skip_mode, sched,
+        shape=shape, interpret=interpret, precision=precision,
+    )
+
+
+def sorted_ttm_core(
+    nz: SortedNonzeros,
+    factors: Sequence[jax.Array],
+    skip_mode: int,
+    sched,
+    *,
+    shape: Sequence[int],
+    interpret: bool,
+    precision: str = "fp32",
+) -> jax.Array:
     """Fused core update (Eq. 12): G_(N) = U_N^T Y_(N) WITHOUT materializing
     Y_(N) — the megakernel re-streams the nonzeros through the Kron→scatter
     pipeline into VMEM scratch and contracts each finished row block against
-    the (just updated) factor in the same grid step. The gathers match the
-    mode-``skip_mode`` unfolding's exactly, so inside one compiled sweep XLA
-    dedups them; the (I_n x K) unfolding itself never crosses HBM a second
-    time. Returns (R_N, prod_{t != skip} R_t) f32.
+    the (just updated) factor in the same grid step. ``nz`` is the
+    mode-``skip_mode`` unfolding's own sorted operands, so the compiled
+    pipeline orders them once for both; the (I_n x K) unfolding itself never
+    crosses HBM a second time. Returns (R_N, prod_{t != skip} R_t) f32.
 
     Orders > 3 fall back to the split path (chained Kron + blocked TTM): the
     megakernel streams exactly two operand blocks, the paper's case.
     """
-    n = len(shape)
     n_rows = int(shape[skip_mode])
     u = factors[skip_mode]
-    if indices.shape[0] == 0:
+    if nz.values.shape[0] == 0:
         from repro.core.kron import zero_unfolding
 
         y0 = zero_unfolding(tuple(shape), factors, skip_mode)
         return jnp.zeros((u.shape[1], y0.shape[1]), dtype=jnp.float32)
-    rows, vals = _gathered_block_rows(indices, values, factors, skip_mode, sched, n)
+    rows = _gathered_block_rows(nz, factors, skip_mode)
     if len(rows) == 2:
         # the megakernel's Kron accumulation and its TTM are one kernel,
         # counted with the Kron kernels
         with jax.named_scope(stages.KRON):
             return kron_kernel.fused_kron_scatter_ttm_pallas(
-                rows[0], rows[1], vals, u, sched, n_rows, interpret=interpret,
+                rows[0], rows[1], nz.values, u, sched, n_rows, interpret=interpret,
                 precision=precision,
             )
-    y = sparse_ttm_chain_device(
-        indices, values, factors, skip_mode, sched,
+    y = sorted_ttm_chain(
+        nz, factors, skip_mode, sched,
         shape=shape, interpret=interpret, precision=precision,
     )
     with jax.named_scope(stages.CORE):

@@ -348,3 +348,111 @@ def test_rebound_engine_does_not_pin_old_tensor():
     fs_b = [jnp.zeros((s, 3), jnp.float32) for s in coo_b.shape]
     out = eng.mode_unfolding(coo_b, fs_b, 0)
     assert np.asarray(out).shape == (20, 9)
+
+
+# ---------------------------------------------------------------------------
+# 7. The nonzeros are ordered once per program call: on the pallas engine
+#    the gathers of ``indices`` and ``values`` into each mode's schedule
+#    order run before the sweep loop, which reads only their results.
+# ---------------------------------------------------------------------------
+
+
+def _pallas_program_jaxpr(shape, ranks, program, fuse_core=False):
+    coo = random_sparse_tensor(shape, 0.08, seed=61)
+    eng = E.make_engine("pallas", interpret=True)
+    scheds = tuple(eng.device_schedule(coo, m) for m in range(len(shape)))
+    fs = tuple(jnp.ones((s, r), jnp.float32) for s, r in zip(shape, ranks))
+    statics = dict(shape=tuple(coo.shape), ranks=ranks, method="householder",
+                   engine_name="pallas", interpret=True, use_reuse=False,
+                   fuse_core=fuse_core)
+    one, zero = jnp.float32(1), jnp.float32(0)
+    if program == "scan":
+        traced = hooi._scan_sweeps.trace(coo.indices, coo.values, fs, one, zero, scheds,
+                                         n_iter=3, **statics)
+    else:
+        traced = hooi._segment_scan_sweeps.trace(
+            coo.indices, coo.values, fs, jnp.zeros(ranks, jnp.float32), one, zero,
+            jnp.float32(np.inf), jnp.asarray(False), jnp.int32(0), jnp.int32(3), scheds,
+            segment_len=3, **statics)
+    return traced.jaxpr.jaxpr
+
+
+@pytest.mark.parametrize("shape,ranks,program,fuse_core", [
+    ((12, 10, 8), (3, 3, 2), "scan", False),
+    ((12, 10, 8), (3, 3, 2), "segment", False),
+    ((10, 9, 8, 7), (3, 2, 2, 2), "scan", False),
+    ((12, 10, 8), (3, 3, 2), "scan", True),
+])
+def test_order_gathers_run_once_before_the_sweeps(shape, ranks, program, fuse_core):
+    jaxpr = _pallas_program_jaxpr(shape, ranks, program, fuse_core)
+    indices, values = jaxpr.invars[:2]
+    (loop,) = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    # the sweep loop is not handed the tensor, so no gather in its body can
+    # read it; it reads the sorted operands the program made before it
+    assert indices not in loop.invars and values not in loop.invars
+    for arg in (indices, values):
+        readers = [e.primitive.name for e in jaxpr.eqns if arg in e.invars]
+        # one order gather per mode, fused core or not, at any order
+        assert readers == ["gather"] * len(shape)
+
+
+def test_pallas_program_reads_each_calls_values():
+    """The sorted values are made from the values of the call: a re-fit of
+    the same nonzeros with new counts on a warm plan gives what a fresh plan
+    gives on them."""
+    from repro import tucker
+    from repro.core.coo import SparseCOO
+
+    spec = tucker.TuckerSpec(shape=(14, 12, 10), ranks=(3, 2, 2), method="householder",
+                             engine="pallas", n_iter=3)
+    coo = random_sparse_tensor(spec.shape, 0.1, seed=62)
+    warm = tucker.plan(spec)
+    warm(coo)
+    counts = jnp.asarray(np.random.default_rng(63).poisson(3.0, coo.nnz) + 1, jnp.float32)
+    res = warm(SparseCOO(coo.indices, counts, spec.shape))
+    assert res.schedule_builds == 0  # the same nonzeros: schedules reused
+    fresh = tucker.plan(spec)(SparseCOO(jnp.array(coo.indices), counts, spec.shape))
+    np.testing.assert_array_equal(res.fit_history, fresh.fit_history)
+    np.testing.assert_array_equal(np.asarray(res.core), np.asarray(fresh.core))
+
+
+@pytest.mark.parametrize("run", ["python", "segment2", "segment5"])
+def test_pallas_pipelines_give_the_same_decomposition(run, tmp_path):
+    """The scan program, the snapshot segment program and the per-sweep
+    driver run the same order gathers and kernels on one tensor."""
+    from repro import tucker
+
+    kw = dict(shape=(14, 12, 10), ranks=(3, 2, 2), method="householder", engine="pallas",
+              n_iter=5, tol=0.0)
+    coo = random_sparse_tensor(kw["shape"], 0.1, seed=64)
+    want = tucker.plan(tucker.TuckerSpec(**kw))(coo)
+    if run == "python":
+        spec = tucker.TuckerSpec(pipeline="python", **kw)
+    else:
+        every = int(run[len("segment"):])
+        spec = tucker.TuckerSpec(snapshot=tucker.SnapshotSpec(
+            every_n_sweeps=every, directory=str(tmp_path)), **kw)
+    got = tucker.plan(spec)(coo)
+    np.testing.assert_array_equal(np.asarray(got.core), np.asarray(want.core))
+    for a, b in zip(got.factors, want.factors):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if run == "python":  # its fit is eager float32 ops, rounding apart from the fused ones
+        np.testing.assert_allclose(got.fit_history, want.fit_history, rtol=1e-6)
+    else:
+        np.testing.assert_array_equal(got.fit_history, want.fit_history)
+
+
+@pytest.mark.parametrize("fuse_core", [False, True])
+def test_pallas_program_on_an_empty_tensor(fuse_core):
+    """With no nonzeros there is nothing to order: every unfolding the
+    program builds is the zero unfolding, so the core is zero."""
+    from repro import tucker
+    from repro.core.coo import SparseCOO
+
+    coo = SparseCOO.from_parts(np.zeros((0, 3), np.int32), np.zeros((0,), np.float32),
+                               (10, 8, 6))
+    p = tucker.plan(tucker.TuckerSpec(shape=coo.shape, ranks=(3, 3, 2), engine="pallas",
+                                      n_iter=2))
+    p.engine.fuse_core = fuse_core
+    res = p(coo)
+    assert res.core.shape == (3, 3, 2) and not np.asarray(res.core).any()

@@ -24,10 +24,11 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import hooi
+from repro.core import hooi, stages
 from repro.core.coo import SparseCOO
 from repro.kernels import kron_kernel, ttm_kernel
 from repro.sparse.layout import DeviceSchedule, build_mode_layout
+from repro.utils import hlo
 
 R, BN, BI, ROWS = 16, 128, 128, 12_092
 NBLK = 1024  # nnz blocks streamed by one kernel call
@@ -117,7 +118,8 @@ def _nell2_coo(nnz, seed=0):
 @pytest.mark.parametrize("precision", PRECISIONS)
 def test_scan_program_compiles_for_v5e(one_chip, precision):
     """The whole 5-sweep Pallas program of a nell-2 plan at 2^18 nonzeros:
-    Mosaic kernels inside, and it fits one chip's HBM."""
+    Mosaic kernels inside, it fits one chip's HBM, and its order gathers run
+    once, outside the sweep loop."""
     coo = _nell2_coo(2**18)
     ranks = (R, R, R)
 
@@ -136,9 +138,27 @@ def test_scan_program_compiles_for_v5e(one_chip, precision):
         engine_name="pallas", interpret=False, use_reuse=False,
         precision=precision,
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+    # the nonzeros are put in each mode's schedule order once, before the
+    # sweep loop: JAX traced no order gather inside it, and the compiler put
+    # none there
+    order_gathers = [m.group(1) for m in re.finditer(r'op_name="([^"]*)"', text)
+                     if stages.ORDER_GATHER in m.group(1)]
+    assert order_gathers and not [p for p in order_gathers if "/while/" in p]
+    assert not [ln for ln in loop_lines(text) if stages.ORDER_GATHER in ln]
+
+
+def loop_lines(text):
+    """The instructions of every computation a ``while`` loop runs."""
+    comps = hlo.split_computations(text)
+    bodies = re.findall(r"\bwhile\(.*?body=%?([\w.\-]+)", text)
+    assert bodies
+    inside = {name for b in bodies
+              for name, runs in hlo.computation_multipliers(comps, entry=b).items() if runs}
+    return [ln for name in inside for ln in comps[name].lines]
 
 
 def test_scan_program_names_its_stages_for_v5e(one_chip, monkeypatch):
@@ -149,8 +169,6 @@ def test_scan_program_names_its_stages_for_v5e(one_chip, monkeypatch):
     slices) carry no metadata. The scopes change no instruction, and no
     kernel beyond the source locations its serialized body carries."""
     from test_stages import STAGE, assert_one_stage_each, without_metadata
-
-    from repro.core import stages
 
     coo = _nell2_coo(2**14)
 
