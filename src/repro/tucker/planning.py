@@ -112,6 +112,11 @@ _MX_PLAN_EVICTIONS = _obs_registry.counter(
 _MX_SNAPSHOTS = _obs_registry.counter(
     "repro_snapshots_written_total", "sweep-carry snapshots spilled to disk"
 )
+_MX_COLLECTIVE_BYTES = _obs_registry.counter(
+    "repro_collective_bytes_total",
+    "bytes all-reduced across chips by sharded sweeps (psum payload per "
+    "sweep times sweeps run)",
+)
 
 
 def _attach_trace_summary(results: Any, root_span: Any) -> None:
@@ -903,6 +908,10 @@ class TuckerPlan:
             sched = eng.shard_schedule(
                 coo, self.mesh, self._nnz_axes, pad_nnz_to=pad_nnz_to
             )
+            coll_bytes = psum_bytes_per_sweep(
+                spec.shape, spec.ranks,
+                dtype=jnp.promote_types(coo.values.dtype, jnp.float32),
+            )
             if self._sharded_segment_program is None:  # once per plan
                 self._sharded_segment_program = _hooi.build_sharded_program(
                     self.mesh, self._nnz_axes,
@@ -1003,12 +1012,15 @@ class TuckerPlan:
                 # the one host sync per segment (the snapshot layer's
                 # overhead): the carry scalars decide loop exit and ride
                 # into the manifest.
+                sweeps0 = n_done
                 prev_err, done, n_done = (
                     float(np.asarray(prev_err_d)),
                     bool(np.asarray(done_d)),
                     int(np.asarray(n_done_d)),
                 )
                 dsp.set_attr("sweeps_run", n_done)
+            if self.spec.shard is not None:
+                _MX_COLLECTIVE_BYTES.inc(coll_bytes * (n_done - sweeps0))
             if done or n_done >= spec.n_iter:
                 save(n_done, "final")
             elif snap.every_seconds is None:
@@ -1034,10 +1046,7 @@ class TuckerPlan:
         res.resumed_from_sweep = resumed_from
         res.retries = retries
         if self.spec.shard is not None:
-            res.collective_bytes_per_sweep = psum_bytes_per_sweep(
-                spec.shape, spec.ranks,
-                dtype=jnp.promote_types(coo.values.dtype, jnp.float32),
-            )
+            res.collective_bytes_per_sweep = coll_bytes
             res.shard_imbalance = sched.imbalance
         return res
 
@@ -1076,6 +1085,7 @@ class TuckerPlan:
             n_done = int(np.sum(hist != _hooi._SKIPPED))
             dsp.set_attr("sweeps_run", n_done)
             dsp.set_attr("retraces", _total_traces() - traces0)
+        _MX_COLLECTIVE_BYTES.inc(coll_bytes * n_done)
         res = self._result(
             core, list(fs), hist[:n_done],
             engine=eng.name,

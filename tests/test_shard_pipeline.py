@@ -344,3 +344,28 @@ def test_sharded_plan_single_device_inprocess():
     assert res.dispatches == 1
     assert res.collective_bytes_per_sweep is not None
     assert res.shard_imbalance == 0.0
+
+
+@pytest.mark.parametrize("snapshot", [False, True])
+def test_collective_bytes_counter_counts_sweeps_run(tmp_path, snapshot):
+    """``repro_collective_bytes_total`` grows by the psum payload of a sweep
+    times the sweeps run, on the plain sharded program and on the sharded
+    snapshot segments alike (one device: the same shard_map program)."""
+    from repro import obs, tucker
+    from repro.core.distributed import psum_bytes_per_sweep
+    from repro.sparse.generators import random_sparse_tensor
+
+    coo = random_sparse_tensor((10, 9, 8), 0.1, seed=3)
+    snap = (tucker.SnapshotSpec(every_n_sweeps=2, directory=str(tmp_path))
+            if snapshot else None)
+    spec = tucker.TuckerSpec(shape=coo.shape, ranks=(2, 2, 2), method="gram",
+                             n_iter=3, shard=tucker.ShardSpec(num_devices=1),
+                             snapshot=snap)
+    counter = obs.registry.counter("repro_collective_bytes_total")
+    before = counter.value
+    res = tucker.plan(spec)(coo)
+    assert res.n_sweeps == 3
+    want = psum_bytes_per_sweep(coo.shape, (2, 2, 2)) * 3
+    assert res.collective_bytes_per_sweep * 3 == want
+    assert counter.value - before == want
+    assert "repro_collective_bytes_total" in obs.registry.render_prometheus()
