@@ -138,7 +138,7 @@ def vmem_bytes(
     """Modeled VMEM working set of the busiest grid step.
 
     The sweep's resident blocks: the Kron operand blocks a (bn, Ra) and
-    b (bn, Rb) at operand precision, value/rel columns, the f32 Y scratch
+    b (bn, Rb) at operand precision, value/rel rows, the f32 Y scratch
     (bi, K), and — fused layout — the U block (bi, Rp) plus the resident
     core output (Rp, K). The TTM tile (bl x bk operand + bl x R output) is
     counted too; the max over the two kernels is what must fit."""
@@ -152,7 +152,7 @@ def vmem_bytes(
     ra = max(ranks)
     kron = (
         cfg.bn * (ra + ra) * eb  # a, b operand blocks
-        + cfg.bn * 2 * 4  # v, rel columns (f32/i32)
+        + cfg.bn * 2 * 4  # v, rel rows (f32/i32)
         + cfg.bi * k_max * 4  # Y scratch / output block (f32 accum)
     )
     if cfg.layout == "fused":

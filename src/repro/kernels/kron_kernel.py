@@ -252,27 +252,105 @@ def _mask_unvisited(out: jax.Array, plan, n_rows: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _fused_kernel(blkmap_ref, first_ref, a_ref, b_ref, v_ref, rel_ref, o_ref):
-    """One nnz block: build the Kron contributions (VPU outer product) and
-    immediately accumulate them into the resident Y row block (MXU one-hot
-    matmul) — the contrib matrix never round-trips through HBM. This is the
-    closest TPU analogue of the paper's fully pipelined FPGA dataflow, where
-    multiplier outputs feed the BRAM accumulator directly."""
+def _expansions(ra: int, rb: int):
+    """The 0/1 matrices that spread factor rows over Kron columns:
+    ``E_a[i, Rb*i + j] = E_b[j, Rb*i + j] = 1``, Rb fastest (paper Alg. 4
+    line 4: c[R3*i + j] = a[i] * b[j])."""
+    col = np.arange(ra * rb)
+    e_a = col // rb == np.arange(ra)[:, None]
+    e_b = col % rb == np.arange(rb)[:, None]
+    return jnp.asarray(e_a, jnp.float32), jnp.asarray(e_b, jnp.float32)
+
+
+def _bf16_parts(x):
+    """``x`` as f32 terms that sum to it exactly and each hold a bf16 value,
+    so that one bf16 MXU pass reads each term whole: ``x`` itself when it
+    was loaded as bf16, else three. Each term keeps the top 8 significant
+    bits of what is left (the low 16 bits masked off), and 8 + 8 + 8 bits
+    cover an f32 significand."""
+    if x.dtype == jnp.bfloat16:
+        return (x.astype(jnp.float32),)
+    parts = []
+    for _ in range(2):
+        bits = jax.lax.bitcast_convert_type(x, jnp.int32) & jnp.int32(-65536)
+        top = jax.lax.bitcast_convert_type(bits, jnp.float32)
+        parts.append(top)
+        x = x - top
+    return (*parts, x)
+
+
+def _expand(x, e):
+    """``x @ e`` exactly for a 0/1 matrix ``e``: one pass at the MXU's
+    default (single bf16) precision per term of :func:`_bf16_parts`, each
+    product a copy of one term, summed in f32."""
+    out = None
+    for part in _bf16_parts(x):
+        y = jnp.dot(part, e, precision=jax.lax.Precision.DEFAULT,
+                    preferred_element_type=jnp.float32)
+        out = y if out is None else out + y
+    return out
+
+
+def _kron_block(a, b, e_a, e_b):
+    """Lane-dense Kron rows, (BN, Ra) x (BN, Rb) -> (BN, Ra*Rb) f32: both
+    factor rows are spread over the Kron columns on the MXU and multiplied
+    once on the VPU, so ``kron[t, Rb*i + j]`` is ``a[t, i] * b[t, j]`` rounded
+    once to f32, as the outer product would give it."""
+    return _expand(a, e_a) * _expand(b, e_b)
+
+
+def _scatter_block(rel, v, kron, bi):
+    """The block's Y rows: ``onehot @ kron`` with the one-hot built in
+    (BI, BN) orientation from lane-dense (1, BN) rows, ``onehot[r, t] =
+    v[t] if rel[t] == r`` (a sublane iota against ``rel`` broadcast over
+    sublanes), so the MXU contraction needs no transpose. Padding slots carry
+    ``v = 0``. Both operands are f32, so the contraction runs at the default
+    matmul precision in force (``highest`` where fp32 is asked for)."""
+    hit = rel == jax.lax.broadcasted_iota(jnp.int32, (bi, rel.shape[1]), 0)
+    onehot = jnp.where(hit, v, 0.0)
+    return jnp.dot(onehot, kron, preferred_element_type=jnp.float32)
+
+
+def _fused_kernel(
+    blkmap_ref, first_ref, a_ref, b_ref, v_ref, rel_ref, ea_ref, eb_ref, o_ref
+):
+    """One nnz block: build the Kron rows and immediately accumulate them
+    into the resident Y row block (MXU one-hot matmul) — the contrib matrix
+    never round-trips through HBM. This is the closest TPU analogue of the
+    paper's fully pipelined FPGA dataflow, where multiplier outputs feed the
+    BRAM accumulator directly. Every tile is lane-dense: the values and row
+    offsets arrive as (1, BN) rows and the Kron rows as (BN, Ra*Rb)."""
     blk = pl.program_id(0)
 
     @pl.when(first_ref[blk] == 1)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    # (BN, 1) f32 values, zero on padding rows
-    contrib = _kron_rows(a_ref[...], b_ref[...]) * v_ref[...]
-    bn = contrib.shape[0]
-    rel = rel_ref[...]  # (BN, 1) int32
-    bi = o_ref.shape[0]
-    onehot = (rel == jax.lax.broadcasted_iota(jnp.int32, (bn, bi), 1)).astype(
-        jnp.float32
-    )
-    o_ref[...] += jnp.dot(onehot.T, contrib, preferred_element_type=jnp.float32)
+    kron = _kron_block(a_ref[...], b_ref[...], ea_ref[...], eb_ref[...])
+    o_ref[...] += _scatter_block(rel_ref[...], v_ref[...], kron, o_ref.shape[0])
+
+
+def _stream_specs(bn, ra, rb):
+    """BlockSpecs of the operands every fused kernel streams: the two factor
+    row blocks, the values and row offsets as (1, BN) rows of their
+    (nblocks, 1, BN) views, and the two constant expansions (fetched once)."""
+    return [
+        pl.BlockSpec((bn, ra), lambda i, *_: (i, 0)),
+        pl.BlockSpec((bn, rb), lambda i, *_: (i, 0)),
+        pl.BlockSpec((None, 1, bn), lambda i, *_: (i, 0, 0)),
+        pl.BlockSpec((None, 1, bn), lambda i, *_: (i, 0, 0)),
+        pl.BlockSpec((ra, ra * rb), lambda i, *_: (0, 0)),
+        pl.BlockSpec((rb, ra * rb), lambda i, *_: (0, 0)),
+    ]
+
+
+def _stream_operands(a, b, v, rel, bn, precision):
+    """The operands :func:`_stream_specs` describes. ``P = nblocks * BN`` by
+    the schedule's construction, so the (nblocks, 1, BN) views are reshapes."""
+    a, b = _cast_operands(precision, a, b)
+    rows = (-1, 1, bn)
+    return (a, b, v.astype(jnp.float32).reshape(rows), rel.reshape(rows),
+            *_expansions(a.shape[1], b.shape[1]))
 
 
 @functools.partial(
@@ -284,23 +362,17 @@ def _fused_call(
     nblocks = blkmap.shape[0]
     n_row_blocks = -(-n_rows // bi)
     ra, rb = a.shape[1], b.shape[1]
-    a, b = _cast_operands(precision, a, b)
     out = pl.pallas_call(
         _fused_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(nblocks,),
-            in_specs=[
-                pl.BlockSpec((bn, ra), lambda blk, m, f: (blk, 0)),
-                pl.BlockSpec((bn, rb), lambda blk, m, f: (blk, 0)),
-                pl.BlockSpec((bn, 1), lambda blk, m, f: (blk, 0)),
-                pl.BlockSpec((bn, 1), lambda blk, m, f: (blk, 0)),
-            ],
+            in_specs=_stream_specs(bn, ra, rb),
             out_specs=pl.BlockSpec((bi, ra * rb), lambda blk, m, f: (m[blk], 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((n_row_blocks * bi, ra * rb), jnp.float32),
         interpret=interpret,
-    )(blkmap, first, a, b, v[:, None].astype(jnp.float32), rel[:, None])
+    )(blkmap, first, *_stream_operands(a, b, v, rel, bn, precision))
     return out[:n_rows]
 
 
@@ -342,17 +414,18 @@ def fused_kron_scatter_pallas(
 
 
 def _mega_kernel(
-    blkmap_ref, first_ref, last_ref, a_ref, b_ref, v_ref, rel_ref, u_ref,
-    g_ref, y_ref,
+    blkmap_ref, first_ref, last_ref, a_ref, b_ref, v_ref, rel_ref, ea_ref,
+    eb_ref, u_ref, g_ref, y_ref,
 ):
     """One nnz block of the fused core update G_(N) = U_N^T Y_(N) (Eq. 12):
     rebuild the target Y row block in VMEM scratch from the streamed nonzeros
-    (Alg. 4 outer products + one-hot scatter — Y never touches HBM in this
-    pass), then, at each row-block group's LAST nnz block, contract the
-    finished block into the grid-resident (R, K) core accumulator. The output
-    block's index map is constant, so ``g_ref`` stays in VMEM for the whole
-    grid (Pallas revisiting rule) — the closest TPU analogue of the paper's
-    FPGA keeping both the BRAM row batch and the TTM accumulator on chip."""
+    (the fused kernel's lane-dense Kron rows and one-hot scatter — Y never
+    touches HBM in this pass), then, at each row-block group's LAST nnz block,
+    contract the finished block into the grid-resident (R, K) core
+    accumulator. The output block's index map is constant, so ``g_ref`` stays
+    in VMEM for the whole grid (Pallas revisiting rule) — the closest TPU
+    analogue of the paper's FPGA keeping both the BRAM row batch and the TTM
+    accumulator on chip."""
     blk = pl.program_id(0)
 
     @pl.when(blk == 0)
@@ -363,15 +436,8 @@ def _mega_kernel(
     def _init_rows():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    # (BN, 1) f32 values, zero on padding rows
-    contrib = _kron_rows(a_ref[...], b_ref[...]) * v_ref[...]
-    bn = contrib.shape[0]
-    rel = rel_ref[...]  # (BN, 1) int32
-    bi = y_ref.shape[0]
-    onehot = (rel == jax.lax.broadcasted_iota(jnp.int32, (bn, bi), 1)).astype(
-        jnp.float32
-    )
-    y_ref[...] += jnp.dot(onehot.T, contrib, preferred_element_type=jnp.float32)
+    kron = _kron_block(a_ref[...], b_ref[...], ea_ref[...], eb_ref[...])
+    y_ref[...] += _scatter_block(rel_ref[...], v_ref[...], kron, y_ref.shape[0])
 
     @pl.when(last_ref[blk] == 1)
     def _contract():
@@ -400,17 +466,14 @@ def _mega_call(
         u.astype(jnp.float32),
         ((0, n_row_blocks * bi - u.shape[0]), (0, rp - r)),
     )
-    a, b, up = _cast_operands(precision, a, b, up)
+    (up,) = _cast_operands(precision, up)
     out = pl.pallas_call(
         _mega_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(nblocks,),
             in_specs=[
-                pl.BlockSpec((bn, ra), lambda blk, m, f, e: (blk, 0)),
-                pl.BlockSpec((bn, rb), lambda blk, m, f, e: (blk, 0)),
-                pl.BlockSpec((bn, 1), lambda blk, m, f, e: (blk, 0)),
-                pl.BlockSpec((bn, 1), lambda blk, m, f, e: (blk, 0)),
+                *_stream_specs(bn, ra, rb),
                 pl.BlockSpec((bi, rp), lambda blk, m, f, e: (m[blk], 0)),
             ],
             out_specs=pl.BlockSpec((rp, k), lambda blk, m, f, e: (0, 0)),
@@ -418,7 +481,7 @@ def _mega_call(
         ),
         out_shape=jax.ShapeDtypeStruct((rp, k), jnp.float32),
         interpret=interpret,
-    )(blkmap, first, last, a, b, v[:, None].astype(jnp.float32), rel[:, None], up)
+    )(blkmap, first, last, *_stream_operands(a, b, v, rel, bn, precision), up)
     return out[:r]
 
 

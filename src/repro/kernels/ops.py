@@ -56,6 +56,7 @@ def sparse_ttm_chain_kernel(
     *,
     interpret: Optional[bool] = None,
     fused: bool = True,
+    precision: str = "fp32",
 ) -> jax.Array:
     """Full Alg. 2 line 5 on the kernel path.
 
@@ -78,7 +79,7 @@ def sparse_ttm_chain_kernel(
     # are host numpy (a ScatterPlan / SortedCOO) or device arrays.
     return sparse_ttm_chain_device(
         coo.indices, coo.values, factors, skip_mode, plan,
-        shape=tuple(coo.shape), interpret=interp, fused=fused,
+        shape=tuple(coo.shape), interpret=interp, fused=fused, precision=precision,
     )
 
 

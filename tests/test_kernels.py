@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.coo import SparseCOO
-from repro.kernels import ops, ref
+from repro.kernels import kron_kernel, ops, ref
 from repro.kernels.kron_kernel import build_scatter_plan, scatter_rows_pallas
 from repro.sparse.generators import random_sparse_tensor
 
@@ -56,16 +56,77 @@ def test_scatter_kernel(n_rows, nnz):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+def _sparse_with_empty_row_block():
+    """Nonzeros in mode 0's first and third 128-row blocks only: the second
+    row block is never visited, and each group ends in padding slots."""
+    rng = np.random.default_rng(5)
+    shape = (300, 12, 10)
+    rows = np.concatenate([np.arange(128), np.arange(256, shape[0])])
+    lin = rng.choice(len(rows) * shape[1] * shape[2], size=900, replace=False)
+    r, j, k = np.unravel_index(lin, (len(rows), shape[1], shape[2]))
+    idx = np.stack([rows[r], j, k], axis=1)
+    return SparseCOO.from_parts(idx, rng.standard_normal(900).astype(np.float32), shape)
+
+
+TENSORS = {
+    "uniform": lambda: random_sparse_tensor((40, 30, 20), 0.02, seed=2),
+    "empty_row_block": _sparse_with_empty_row_block,
+}
+
+
+@pytest.mark.parametrize("tensor", sorted(TENSORS))
+@pytest.mark.parametrize("precision", ["fp32", "bf16_fp32acc"])
+@pytest.mark.parametrize(
+    "ranks", [(6, 5, 4), (4, 8, 8), (8, 16, 16), (16, 16, 16)],
+    ids=lambda r: "x".join(map(str, r)),
+)
 @pytest.mark.parametrize("mode", [0, 1, 2])
-def test_full_sparse_chain_kernel_vs_core(mode):
-    coo = random_sparse_tensor((40, 30, 20), 0.02, seed=2)
+def test_full_sparse_chain_kernel_vs_core(mode, ranks, precision, tensor):
+    """The fused kernel through ``ops.sparse_ttm_chain_kernel`` at Kron
+    widths Ra*Rb of 20 to 256, on uniform nonzeros and on a mode with an
+    unvisited row block; bf16 loads are compared with the reference on the
+    same bf16-rounded factor rows."""
+    coo = TENSORS[tensor]()
     fs = [jnp.asarray(RNG.standard_normal((s, r)).astype(np.float32))
-          for s, r in zip(coo.shape, (6, 5, 4))]
-    got = np.asarray(ops.sparse_ttm_chain_kernel(coo, fs, mode))
+          for s, r in zip(coo.shape, ranks)]
+    got = np.asarray(ops.sparse_ttm_chain_kernel(coo, fs, mode, precision=precision))
+    if precision == "bf16_fp32acc":
+        fs = [f.astype(jnp.bfloat16).astype(jnp.float32) for f in fs]
     want = np.asarray(
         ref.sparse_ttm_chain_ref(coo.indices, coo.values, fs, mode, coo.shape[mode])
     )
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("ra,rb", [(4, 8), (8, 16), (16, 16)])
+def test_lane_dense_kron_rows_are_exact(ra, rb):
+    """The fused kernels' Kron rows, spread over the columns on the MXU in
+    exact bf16 terms, equal the f32 outer product bit for bit, on values
+    that use all 24 bits of the significand over a wide exponent range."""
+    from jax.experimental import pallas as pl
+
+    n = 128
+    def draw(r):
+        x = RNG.standard_normal((n, r)) * 2.0 ** RNG.integers(-20, 20, (n, r))
+        return x.astype(np.float32)
+
+    a, b = draw(ra), draw(rb)
+
+    def body(a_ref, b_ref, ea_ref, eb_ref, o_ref):
+        o_ref[...] = kron_kernel._kron_block(
+            a_ref[...], b_ref[...], ea_ref[...], eb_ref[...])
+
+    got = pl.pallas_call(
+        body, out_shape=jax.ShapeDtypeStruct((n, ra * rb), jnp.float32),
+        interpret=True,
+    )(jnp.asarray(a), jnp.asarray(b), *kron_kernel._expansions(ra, rb))
+    np.testing.assert_array_equal(
+        np.asarray(got), np.einsum("ti,tj->tij", a, b).reshape(n, -1))
+    # what the MXU reads in one bf16 pass: terms exact in bf16 that sum to a
+    parts = [np.asarray(p) for p in kron_kernel._bf16_parts(jnp.asarray(a))]
+    for p in parts:
+        np.testing.assert_array_equal(p, p.astype(jnp.bfloat16).astype(np.float32))
+    np.testing.assert_array_equal(parts[0] + parts[1] + parts[2], a)
 
 
 @pytest.mark.parametrize(

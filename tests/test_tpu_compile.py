@@ -115,29 +115,41 @@ def _nell2_coo(nnz, seed=0):
     return SparseCOO.from_parts(idx, np.ones((nnz,), np.float32), NELL2)
 
 
-@pytest.mark.parametrize("precision", PRECISIONS)
-def test_scan_program_compiles_for_v5e(one_chip, precision):
-    """The whole 5-sweep Pallas program of a nell-2 plan at 2^18 nonzeros:
-    Mosaic kernels inside, it fits one chip's HBM, and its order gathers run
-    once, outside the sweep loop."""
+@pytest.fixture(scope="module")
+def nell2_scan(one_chip):
+    """The whole 5-sweep Pallas program of a nell-2 plan at 2^18 nonzeros,
+    compiled once per precision for the tests that read it, with its
+    schedules' padded slot counts."""
     coo = _nell2_coo(2**18)
-    ranks = (R, R, R)
 
     def spec_of(x):
         return _sds(one_chip, x.shape, x.dtype)
 
-    scheds = tuple(
-        jax.tree.map(spec_of, DeviceSchedule.from_layout(build_mode_layout(coo, m)))
-        for m in range(3)
-    )
+    layouts = [DeviceSchedule.from_layout(build_mode_layout(coo, m)) for m in range(3)]
+    scheds = tuple(jax.tree.map(spec_of, sched) for sched in layouts)
     factors = tuple(_sds(one_chip, (NELL2[m], R)) for m in range(3))
     scalar = _sds(one_chip, ())
-    compiled = hooi._scan_sweeps.lower(
-        spec_of(coo.indices), spec_of(coo.values), factors, scalar, scalar,
-        scheds, shape=NELL2, ranks=ranks, method="householder", n_iter=5,
-        engine_name="pallas", interpret=False, use_reuse=False,
-        precision=precision,
-    ).compile()
+    compiled = {}
+
+    def get(precision):
+        if precision not in compiled:
+            compiled[precision] = hooi._scan_sweeps.lower(
+                spec_of(coo.indices), spec_of(coo.values), factors, scalar, scalar,
+                scheds, shape=NELL2, ranks=(R, R, R), method="householder",
+                n_iter=5, engine_name="pallas", interpret=False, use_reuse=False,
+                precision=precision,
+            ).compile()
+        return compiled[precision], {sched.order.shape[0] for sched in layouts}
+
+    return get
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+def test_scan_program_compiles_for_v5e(nell2_scan, precision):
+    """The whole 5-sweep Pallas program of a nell-2 plan at 2^18 nonzeros:
+    Mosaic kernels inside, it fits one chip's HBM, and its order gathers run
+    once, outside the sweep loop."""
+    compiled, _ = nell2_scan(precision)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     mem = compiled.memory_analysis()
@@ -149,6 +161,31 @@ def test_scan_program_compiles_for_v5e(one_chip, precision):
                      if stages.ORDER_GATHER in m.group(1)]
     assert order_gathers and not [p for p in order_gathers if "/while/" in p]
     assert not [ln for ln in loop_lines(text) if stages.ORDER_GATHER in ln]
+
+
+FUSED_CALL = re.compile(r"^\s*(?:ROOT )?%?_fused_call[.\d]* = .*custom_call_target=\"tpu_custom_call\"")
+OPERAND_LAYOUTS = re.compile(r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}")
+
+
+@pytest.mark.parametrize("precision", PRECISIONS)
+@pytest.mark.parametrize("program", ["kernel", "scan"])
+def test_fused_kernel_operands_are_lane_dense_for_v5e(one_chip, nell2_scan, program, precision):
+    """The fused Kron-scatter kernel takes the values and row offsets as
+    lane-dense rows, never as (n, 1) columns that a TPU pads to 128 lanes in
+    HBM; so the sweep loop copies no (P, 1) column for it."""
+    if program == "kernel":
+        text = _lower("fused", precision, one_chip).compile().as_text()
+    else:
+        compiled, padded = nell2_scan(precision)
+        text = compiled.as_text()
+        columns = re.compile(r"\[(\d+),1\]\{1,0:T\(8,128\)")
+        assert not [ln for ln in loop_lines(text)
+                    if any(int(p) in padded for p in columns.findall(ln))]
+    calls = [ln for ln in text.splitlines() if FUSED_CALL.match(ln)]
+    assert len(calls) == (1 if program == "kernel" else 3)
+    for ln in calls:
+        operands = OPERAND_LAYOUTS.search(ln).group(1)
+        assert ",1]" not in operands, operands
 
 
 def loop_lines(text):
