@@ -5,7 +5,7 @@ These are the data-movement invariants the paper's hybrid split lives on —
 the TTM/Kron hot loop never leaves the accelerator, donated carries alias
 in place, sharded sweeps psum exactly once per mode — checked statically on
 ``compiled.as_text()`` via the :mod:`repro.utils.hlo` parser, so every
-(engine x pipeline x shard x snapshot x precision) cell can be audited
+(engine x shard x snapshot x precision) cell can be audited
 without executing anything.
 """
 from __future__ import annotations

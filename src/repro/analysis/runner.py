@@ -1,7 +1,7 @@
 """The config-matrix sweep behind ``python -m repro.analysis``.
 
-One :class:`Cell` = one (engine x pipeline x shard x snapshot x precision)
-point: a spec (plus optional prebuilt engine), lowered through
+One :class:`Cell` = one (engine x shard x snapshot x precision) point: a
+spec (plus optional prebuilt engine), lowered through
 ``TuckerPlan.lower_hlo`` and pushed through every applicable contract lint.
 ``run_matrix`` sweeps the default matrix (or a chosen subset), applies the
 committed baseline, and returns a report the CLI/CI gate turns into an

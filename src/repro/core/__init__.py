@@ -5,7 +5,7 @@ Modules mirror the paper's accelerator decomposition:
   ttm.py          dense TTM, module 1 (Sec. III-B, Alg. 3)
   kron.py         sparse Kron-accumulation, module 2 (Sec. III-C, Alg. 4)
   qrp.py          QR with column pivoting, module 3 (Sec. III-D)
-  hooi.py         sweep machinery + compiled pipelines; legacy driver shims
+  hooi.py         sweep machinery + the compiled sweep programs
                   (the public front-end is repro.tucker's plan/execute API)
   engine.py       sweep engine selection: XLA vs Pallas-kernel hot loops
   reconstruct.py  Eq. 7 reconstruction + error metrics
@@ -22,11 +22,7 @@ from repro.core.engine import (
 from repro.core.hooi import (
     HooiResult,
     effective_ranks,
-    hooi_dense,
-    hooi_sparse,
     init_factors,
-    sparse_sweep,
-    tucker_complete_dense,
 )
 from repro.core.kron import (
     kron_rows,

@@ -19,15 +19,15 @@ scales with nnz/devices while collective bytes stay constant
 (:func:`psum_bytes_per_sweep` is that invariant as a number, reported per
 call as ``TuckerResult.collective_bytes_per_sweep``).
 
-The execution path lives in the plan/execute pipeline now: a
+The execution path lives in the plan/execute pipeline: a
 :class:`~repro.tucker.spec.TuckerSpec` with ``shard=ShardSpec(...)`` compiles
 the whole multi-sweep loop as ONE shard_map-wrapped scan program
-(``core.hooi.sharded_scan_program``). The eager per-sweep driver this module
-used to own (``hooi_sparse_distributed``) is a deprecation shim over it.
+(``core.hooi.build_sharded_program``). This module keeps the collective byte
+count and the nonzero sharding helper.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import jax
 import numpy as np
@@ -66,64 +66,3 @@ def shard_nonzeros(
     """
     sched = build_shard_schedule(coo, mesh, tuple(nnz_axes))
     return SparseCOO(sched.indices, sched.values, coo.shape)
-
-
-def hooi_sparse_distributed(
-    coo: SparseCOO,
-    ranks: Sequence[int],
-    mesh: jax.sharding.Mesh,
-    n_iter: int = 5,
-    method: str = "gram",
-    nnz_axes: Optional[Tuple[str, ...]] = None,
-    key: Optional[jax.Array] = None,
-):
-    """Data-parallel Alg. 2 over an arbitrary mesh. Matches the single-device
-    ``hooi_sparse`` bit-for-bit up to psum reduction order.
-
-    .. deprecated:: use ``repro.tucker`` with
-       ``TuckerSpec(shard=ShardSpec(num_devices=...))`` — the planned path
-       compiles the whole multi-sweep loop into one shard_map program (one
-       dispatch per decompose instead of one per sweep) and caches it. This
-       shim flattens ``mesh``'s ``nnz_axes`` into an equivalent 1-axis nnz
-       mesh over the CALLER's devices (nnz-axes order preserved; axes not in
-       ``nnz_axes`` collapse to the first device of each replica group, whose
-       extra copies only duplicated work) and delegates via
-       ``tucker.plan(spec, mesh=...)``.
-    """
-    import warnings
-
-    from repro import tucker  # local import to avoid cycle
-
-    warnings.warn(
-        "hooi_sparse_distributed is deprecated; use repro.tucker.plan with "
-        "TuckerSpec(shard=ShardSpec(num_devices=...)).",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    nnz_axes = tuple(nnz_axes) if nnz_axes is not None else tuple(mesh.axis_names)
-    missing = [a for a in nnz_axes if a not in mesh.axis_names]
-    if missing:
-        raise ValueError(
-            f"nnz axes {missing} are not mesh axes: the mesh has "
-            f"{tuple(mesh.axis_names)}"
-        )
-    n_shards = int(np.prod([mesh.shape[a] for a in nnz_axes]))
-    # keep the caller's device placement: transpose to nnz-axes-major order,
-    # drop the replica axes (first device of each group), flatten to 1 axis.
-    names = tuple(mesh.axis_names)
-    keep = [names.index(a) for a in nnz_axes]
-    drop = [i for i in range(len(names)) if names[i] not in nnz_axes]
-    devs = np.transpose(np.asarray(mesh.devices), keep + drop).reshape(
-        n_shards, -1
-    )[:, 0]
-    shard = tucker.ShardSpec(num_devices=n_shards)
-    flat_mesh = jax.sharding.Mesh(devs, (shard.axis,))
-    spec = tucker.TuckerSpec(
-        shape=tuple(coo.shape),
-        ranks=tuple(ranks),
-        method=method,
-        engine="xla",
-        n_iter=n_iter,
-        shard=shard,
-    )
-    return tucker.plan(spec, mesh=flat_mesh)(coo, key=key)

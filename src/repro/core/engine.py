@@ -27,7 +27,6 @@ from typing import Dict, List, Optional, Sequence
 
 import jax
 
-from repro.core import stages
 from repro.core.coo import SparseCOO
 from repro.obs import registry as _obs_registry, span as _obs_span
 from repro.sparse.layout import (
@@ -272,41 +271,6 @@ class SweepEngine:
             interpret=self.resolved_interpret(),
             precision=self.precision,
         )
-
-    # -- Alg. 2 line 9: core from the last unfolding (module 1) -----------
-    def core_unfolding(self, y_n: jax.Array, u_last: jax.Array) -> jax.Array:
-        """G_(N) = U_N^T Y_(N) (Eq. 12): (R_N, prod_{t != N} R_t)."""
-        with jax.named_scope(stages.CORE):
-            if self.name == "pallas":
-                from repro.kernels import ops
-
-                return ops.ttm(
-                    y_n.T, u_last.T, bl=self.bl, bk=self.bk,
-                    interpret=self.interpret, precision=self.precision,
-                ).T
-            from repro.core.ttm import ttm_unfolded
-
-            return ttm_unfolded(y_n.T, u_last.T).T
-
-    def core_update(
-        self, coo: SparseCOO, factors: Sequence[jax.Array], y_n: jax.Array
-    ) -> jax.Array:
-        """The core update with the engine's layout choice applied: the
-        fused megakernel (``fuse_core``, pallas) re-streams the nonzeros so
-        Y_(N) never crosses HBM a second time; otherwise the split blocked
-        TTM over the already-materialized ``y_n``."""
-        n = coo.ndim
-        if self.name == "pallas" and self.fuse_core:
-            from repro.kernels import ops
-
-            return ops.sparse_ttm_core_device(
-                coo.indices, coo.values, factors, n - 1,
-                self.device_schedule(coo, n - 1),
-                shape=tuple(coo.shape),
-                interpret=self.resolved_interpret(),
-                precision=self.precision,
-            )
-        return self.core_unfolding(y_n, factors[n - 1])
 
 
 def make_engine(

@@ -1,15 +1,12 @@
-"""HOOI sweep machinery + legacy driver shims.
+"""HOOI sweep machinery: the compiled program layer of the decomposition.
 
-This module owns the *compiled program layer* of the decomposition:
-``sparse_sweep`` (one ALS sweep of paper Alg. 2), the jitted per-sweep
-program, the compiled scan-over-sweeps pipeline (``_scan_sweeps``) and its
-vmapped batch variant, plus the trace/dispatch instrumentation the perf
-regression tests read.
-
-The *front-end* lives in ``repro.tucker`` (plan/execute API); the historical
-entrypoints here — ``hooi_dense`` (Alg. 1 baseline), ``hooi_sparse``
-(Alg. 2), ``tucker_complete_dense`` (EM completion) — are thin deprecation
-shims that build a ``TuckerSpec`` and delegate, bit-identically.
+Every sparse fit (paper Alg. 2) runs one compiled scan-over-sweeps program:
+``_scan_sweeps`` on one device, ``_segment_scan_sweeps`` for the snapshot
+layer's resumable segments, ``_batched_scan_sweeps`` vmapped over a batch,
+and ``build_sharded_program`` over a device mesh. All four share the one
+sweep skeleton ``_sweep_scan``. The module also owns the trace/dispatch
+instrumentation the regression tests read. The front end lives in
+``repro.tucker`` (plan/execute API).
 
 Convergence metric: for orthonormal factors produced by SVD/QRP the
 projection identity  ||X - G x {U}||_F^2 = ||X||_F^2 - ||G||_F^2  holds, so
@@ -20,9 +17,8 @@ from __future__ import annotations
 import collections
 import dataclasses
 import threading
-import warnings
 from functools import partial
-from typing import List, Optional, Sequence, Tuple, Union
+from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -30,25 +26,18 @@ import numpy as np
 
 from repro.core import stages
 from repro.core.coo import SparseCOO, fold_dense
-from repro.core.engine import SweepEngine
-from repro.core.kron import (
-    KronReusePlan,
-    sparse_ttm_chain,
-    sparse_ttm_chain_reuse,
-    sparse_ttm_chain_reuse_device,
-)
+from repro.core.kron import sparse_ttm_chain, sparse_ttm_chain_reuse_device
 from repro.core.qrp import factor_update
 from repro.core.ttm import ttm_unfolded
 from repro.obs import registry as _obs_registry
-
-PIPELINES = ("scan", "python")
 
 
 class _MirroredCounter(collections.Counter):
     """A ``collections.Counter`` whose every increment also ticks one
     registry :class:`~repro.obs.metrics.Counter` — the keyed dicts below
     stay the fine-grained source the regression tests read, while the
-    registry (and so Prometheus / the BENCH writers) sees the totals."""
+    registry (and so Prometheus and the benchmark's counters) sees the
+    totals."""
 
     def __init__(self, metric_name: str, help: str) -> None:
         super().__init__()
@@ -72,12 +61,11 @@ class _MirroredCounter(collections.Counter):
             dict.__setitem__(self, key, value)
 
 # -- instrumentation ---------------------------------------------------------
-# SWEEP_TRACE_COUNTS ticks once per *trace* of the compiled sweep pipeline
+# SWEEP_TRACE_COUNTS ticks once per *trace* of the compiled sweep program
 # (inside the traced body, so cache hits don't count) — the no-retrace
-# regression test and benchmarks/sweep_bench.py read it. SWEEP_DISPATCH_COUNTS
-# ticks once per top-level XLA dispatch the sparse driver issues: the scan
-# pipeline is exactly 1 per hooi_sparse call, the legacy python pipeline is 1
-# per sweep.
+# regression tests read it. SWEEP_DISPATCH_COUNTS ticks once per top-level
+# XLA dispatch the sparse driver issues: one per fit, per vmapped batch and
+# per snapshot segment.
 SWEEP_TRACE_COUNTS: collections.Counter = _MirroredCounter(
     "repro_sweep_traces_total",
     "traces of the compiled sweep pipelines (retraces when it keeps rising)",
@@ -129,7 +117,7 @@ def init_factors(
     dtype=None,
 ) -> List[jax.Array]:
     """Alg. 2 line 1: random init (orthonormalized for a sane first sweep).
-    ``dtype=None`` follows the jax x64 flag (the legacy behavior)."""
+    ``dtype=None`` follows the jax x64 flag."""
     if dtype is None:
         dtype = jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
     factors = []
@@ -145,42 +133,6 @@ def init_factors(
                 u = q.astype(dtype)
             factors.append(u)
     return factors
-
-
-# ---------------------------------------------------------------------------
-# Dense HOOI (paper Alg. 1) — deprecation shim over repro.tucker.
-# ---------------------------------------------------------------------------
-
-
-def hooi_dense(
-    x: jax.Array,
-    ranks: Sequence[int],
-    n_iter: int = 5,
-    method: str = "svd",
-    key: Optional[jax.Array] = None,
-    tol: float = 0.0,
-    factors_init: Optional[List[jax.Array]] = None,
-) -> HooiResult:
-    """Standard HOOI on a dense tensor. ``method``: 'svd' (Alg. 1 line 5),
-    'householder' or 'gram' (the paper's QRP replacement, Table II).
-    ``factors_init`` warm-starts the sweep (completion / re-fits).
-
-    .. deprecated:: use ``repro.tucker`` (``decompose(x, ranks)`` or
-       ``plan(TuckerSpec(..., algorithm="dense"))``); this shim delegates.
-    """
-    from repro import tucker
-
-    warnings.warn(
-        "hooi_dense is deprecated; use repro.tucker.decompose / plan "
-        "(TuckerSpec(algorithm='dense')).",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    spec = tucker.TuckerSpec(
-        shape=tuple(x.shape), ranks=tuple(ranks), method=method,
-        n_iter=n_iter, tol=tol, algorithm="dense",
-    )
-    return tucker.plan(spec)(x, key=key, factors_init=factors_init)
 
 
 # ---------------------------------------------------------------------------
@@ -207,58 +159,12 @@ def effective_ranks(shape: Sequence[int], ranks: Sequence[int]) -> List[int]:
     return r
 
 
-def sparse_sweep(
-    coo: SparseCOO,
-    factors: List[jax.Array],
-    ranks: Sequence[int],
-    method: str,
-    reuse_plans: Optional[Sequence[Optional[KronReusePlan]]] = None,
-    engine: Optional[SweepEngine] = None,
-) -> Tuple[List[jax.Array], jax.Array]:
-    """One ALS sweep of Alg. 2 (lines 3-9). Returns (factors, core).
-
-    With ``engine`` set, the hot loops (Kron-accumulation, core TTM) execute
-    on that engine (see ``core.engine``); otherwise the legacy XLA path with
-    optional per-mode ``reuse_plans`` runs.
-    """
-    n = coo.ndim
-    y_n = None
-    for mode in range(n):
-        if engine is not None:
-            y_n = engine.mode_unfolding(coo, factors, mode)
-        else:
-            plan = reuse_plans[mode] if reuse_plans is not None else None
-            if plan is not None:
-                y_n = sparse_ttm_chain_reuse(coo, factors, mode, plan)
-            else:
-                y_n = sparse_ttm_chain(coo, factors, mode)
-        factors[mode] = factor_update(y_n, ranks[mode], method)
-    # Alg. 2 line 9: G <- Y x_N U_N^T on the (dense, small) last unfolding.
-    # y_n is Y_(N): (I_N, R_1*...*R_{N-1}); the TTM module computes
-    # G_(N) = U_N^T Y_(N)  — this is the paper's FPGA TTM (Eq. 12).
-    if engine is not None:
-        g_n = engine.core_update(coo, factors, y_n)  # (R_N, prod R_t)
-    else:
-        with jax.named_scope(stages.CORE):
-            g_n = ttm_unfolded(y_n.T, factors[n - 1].T).T  # (R_N, prod R_t)
-    with jax.named_scope(stages.CORE):
-        core = fold_dense(g_n, n - 1, list(ranks))
-    return factors, core
-
-
-@partial(jax.jit, static_argnames=("shape", "ranks", "method"))
-def _jitted_sweep(indices, values, factors, *, shape, ranks, method):
-    coo = SparseCOO(indices, values, shape)
-    fs, core = sparse_sweep(coo, list(factors), ranks, method, None)
-    return tuple(fs), core
-
-
 # ---------------------------------------------------------------------------
 # Compiled scan-over-sweeps pipeline: the entire multi-sweep HOOI loop is ONE
 # XLA program per (engine, shape, ranks, method, n_iter). Schedules arrive as
 # device-resident pytrees (sparse.layout.DeviceSchedule), factor/core buffers
 # are donated, the ``tol`` early-exit is a cond-masked scan, and the fit
-# history crosses device->host exactly once per hooi_sparse call.
+# history crosses device->host exactly once per call.
 # ---------------------------------------------------------------------------
 
 
@@ -318,9 +224,8 @@ def _sweep_scan(
                 jnp.sqrt(jnp.maximum(xnorm2 - jnp.sum(jnp.square(core)), 0.0))
                 / jnp.sqrt(xnorm2)
             ).astype(jnp.float32)
-            # same rule as the legacy loop: stop once two consecutive sweeps
-            # agree to within tol (never on the first sweep — prev_err
-            # starts at +inf).
+            # stop once two consecutive sweeps agree to within tol (never
+            # on the first sweep — prev_err starts at +inf).
             done = (tol > 0) & jnp.isfinite(prev_err) & (jnp.abs(prev_err - err) < tol)
         return tuple(fs), core, err, done, n_done + jnp.int32(1)
 
@@ -442,8 +347,8 @@ def _scan_sweeps_impl(
     fs, core, hist, _ = _sweep_scan(
         mode_unfolding, core_unfolding, factors, xnorm2, tol,
         ranks=ranks, method=method, n_iter=n_iter,
-        # working precision of the core carry: float64 inputs keep float64
-        # (parity with the per-sweep python driver); float32 stays as before.
+        # working precision of the core carry: float64 inputs keep float64,
+        # float32 and narrower compute in float32.
         core_dtype=jnp.promote_types(values.dtype, jnp.float32),
     )
     return fs, core, hist
@@ -695,107 +600,3 @@ def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
     # hands in freshly-initialized (or defensively copied) buffers, so the
     # replicated inputs can be consumed by the replicated outputs in place.
     return jax.jit(traced, donate_argnums=(2,))
-
-
-def hooi_sparse(
-    coo: SparseCOO,
-    ranks: Sequence[int],
-    n_iter: int = 5,
-    method: str = "householder",
-    key: Optional[jax.Array] = None,
-    tol: float = 0.0,
-    use_kron_reuse: bool = False,
-    engine: Union[str, SweepEngine] = "auto",
-    pipeline: str = "scan",
-) -> HooiResult:
-    """The paper's sparse Tucker decomposition (Alg. 2).
-
-    .. deprecated:: use ``repro.tucker`` — build a ``TuckerSpec`` once, call
-       ``tucker.plan(spec)`` on many tensors (or ``tucker.decompose`` for a
-       one-shot). This shim builds the spec from its kwargs and delegates;
-       results are bit-identical to the plan API.
-
-    Args:
-      coo: the sparse input tensor (COO, paper Table I).
-      ranks: multilinear rank (R_1..R_N).
-      n_iter: max ALS sweeps ("power iterations" in the paper).
-      method: 'householder' (paper QRP), 'gram' (TPU QRP variant) or 'svd'.
-      use_kron_reuse: enable the paper's Kronecker-row dedup (Sec. III-C)
-        on the XLA engine (the Pallas schedule has its own reuse layout).
-      engine: 'xla', 'pallas' or 'auto' — how the sweep's hot loops execute
-        (see ``core.engine``). 'auto' picks pallas on TPU, xla elsewhere. A
-        prebuilt :class:`~repro.core.engine.SweepEngine` is also accepted and
-        reuses its cached (device-resident) schedules across calls.
-      pipeline: 'scan' (default) compiles the whole multi-sweep loop into a
-        single XLA program; 'python' is the legacy per-sweep driver, kept as
-        the benchmark baseline (``benchmarks/sweep_bench.py``).
-    """
-    from repro import tucker
-
-    warnings.warn(
-        "hooi_sparse is deprecated; use repro.tucker.plan / decompose.",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    prebuilt = engine if isinstance(engine, SweepEngine) else None
-    spec = tucker.TuckerSpec(
-        shape=tuple(coo.shape),
-        ranks=tuple(ranks),
-        method=method,
-        engine=prebuilt.name if prebuilt is not None else engine,
-        pipeline=pipeline,
-        n_iter=n_iter,
-        tol=tol,
-        use_kron_reuse=use_kron_reuse,
-    )
-    return tucker.plan(spec, engine=prebuilt)(coo, key=key)
-
-
-def tucker_complete_dense(
-    coo: SparseCOO,
-    ranks: Sequence[int],
-    n_rounds: int = 10,
-    n_iter: int = 2,
-    method: str = "gram",
-    key: Optional[jax.Array] = None,
-) -> HooiResult:
-    """EM-style Tucker completion (paper use cases: MRI reconstruction [27],
-    process-variation prediction [15]): alternate HOOI with imputation of the
-    missing entries from the current reconstruction. Dense working set —
-    intended for the small/medium completion problems of those applications;
-    the pod-scale path keeps X sparse (core.distributed).
-
-    .. deprecated:: use ``repro.tucker`` with ``algorithm="complete"``; this
-       shim delegates.
-    """
-    from repro import tucker
-
-    warnings.warn(
-        "tucker_complete_dense is deprecated; use repro.tucker.decompose("
-        "..., algorithm='complete') / plan.",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    spec = tucker.TuckerSpec(
-        shape=tuple(coo.shape), ranks=tuple(ranks), method=method,
-        n_iter=n_iter, n_rounds=n_rounds, algorithm="complete",
-    )
-    return tucker.plan(spec)(coo, key=key)
-
-
-# ---------------------------------------------------------------------------
-# Operation-count accounting (paper Sections III-B/C/D; used by benchmarks).
-# ---------------------------------------------------------------------------
-
-
-def sweep_call_counts(
-    shape: Sequence[int], ranks: Sequence[int], nnz: int, n_iter: int
-) -> dict:
-    """The paper reports per-dataset totals: #QRP calls, #Kron calls, #TTM.
-    One sweep does N QRP calls and nnz*N Kron rows; one TTM per sweep."""
-    n = len(shape)
-    return {
-        "qrp_calls": n * n_iter + (n - 1),  # paper counts: e.g. Amazon 9 = ...
-        "kron_calls": nnz * n_iter,
-        "ttm_calls": n_iter,
-    }

@@ -50,7 +50,7 @@ def compression_ratio(shape: Sequence[int], ranks: Sequence[int],
     """Dense storage / Tucker storage. With ``include_factors=False`` only
     the core is counted — the convention under which the paper's angiogram
     number (18.57x for rank [30,35] on 130x150) reproduces exactly; the
-    factor-inclusive ratio (1.91x) is also reported in our benchmarks."""
+    factor-inclusive ratio (1.91x) is the default."""
     import numpy as np
 
     dense = float(np.prod(shape))

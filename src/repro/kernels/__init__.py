@@ -9,7 +9,7 @@
   ops              jit'd dispatch wrappers (interpret on CPU, Mosaic on TPU)
   ref              pure-jnp oracles for allclose validation
 
-These kernels are the production path of ``hooi_sparse(..., engine=...)``:
+These kernels are the production path of a plan with ``engine="pallas"``:
 ``core.engine`` streams nonzeros through them on a host-side
 ``sparse.layout.SortedCOO`` schedule. ``tests/test_engine.py`` holds the
 differential harness that gates any change here against the dense oracle.

@@ -67,8 +67,8 @@ def sparse_ttm_chain_kernel(
     The ``plan`` — a ``ScatterPlan`` or a ``sparse.layout.SortedCOO`` (the
     engine's richer schedule, same fields) — plays the role of the paper's
     FPGA dataflow schedule; build it once per (tensor, mode) and reuse
-    across sweeps. ``hooi_sparse(..., engine="pallas")`` does exactly that
-    via ``core.engine.SweepEngine``.
+    across sweeps, as ``core.engine.SweepEngine`` does for a plan with
+    ``engine="pallas"``.
     """
     interp = default_interpret() if interpret is None else interpret
     if coo.nnz and plan is None:

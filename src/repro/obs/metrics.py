@@ -7,8 +7,7 @@ sweep pipelines' trace/dispatch counts, snapshot spills, retry attempts,
 and the serving plane's amortization counters (``ServiceMetrics`` is built
 on these primitives). Every metric registered anywhere in the stack shows
 up in ``registry.render_prometheus()`` (text exposition format, scrapeable)
-and ``registry.snapshot()`` (the JSON dict all four ``BENCH_*.json``
-writers embed).
+and ``registry.snapshot()`` (a plain JSON dict).
 
 Metrics are cheap and always on — unlike spans they don't gate on
 ``obs.configure(enabled=...)``; a counter bump is one lock + one add.

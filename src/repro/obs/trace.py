@@ -14,8 +14,7 @@ Design constraints, in order:
 
 1. **Disabled is free.** The default is off; ``span()`` then returns a
    shared no-op context manager after one attribute check, so instrumented
-   hot paths cost nanoseconds (gated ≤1% of sweep wall-clock by
-   ``benchmarks/sweep_bench.py --trace``).
+   hot paths cost nanoseconds.
 2. **Bounded.** The ring holds ``ring_capacity`` finished spans; a
    long-lived service overwrites its oldest history instead of growing.
 3. **No jax.** Importable from anywhere in the stack (including

@@ -6,13 +6,13 @@ ones that prove (or disprove) it: dispatch count vs. request count, flush
 reasons (did batches fill, or did the timeout fire half-empty?), achieved
 batch sizes, padding overhead from nnz bucketing, and queue/execute/total
 latency distributions (p50/p99). Thread-safe; ``snapshot()`` returns plain
-dicts for JSON benchmarks and CI gates.
+dicts for the benchmark and the tests.
 
 Since the unified telemetry plane (``repro.obs``), every counter here is a
 handle into the process-wide :data:`repro.obs.registry` — labeled
 ``service="svc-N"`` so concurrent services coexist in one exposition — which
 is what puts the amortization counters on ``registry.render_prometheus()``
-and in the BENCH JSON metrics snapshots. The public ``snapshot()`` dict is
+and in ``registry.snapshot()``. The public ``snapshot()`` dict is
 unchanged (bit-compatible with the pre-registry implementation), and one
 ``ServiceMetrics``-level lock still covers every multi-metric update and
 read: a flush's counter bumps land atomically, never as a torn snapshot.

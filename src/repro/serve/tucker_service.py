@@ -34,7 +34,7 @@ Every execute path resolves every ticket it dequeued — error paths fail
 them, and a belt-and-braces guard converts any would-be leak into a pointed
 ``RuntimeError`` rather than a silent ``result()`` hang.
 
-Amortization contract (asserted by ``benchmarks/serve_bench.py`` and the
+Amortization contract (asserted by ``tests/test_tucker_service.py`` and the
 ``serve_soak`` CI gate): under load, dispatches ≈ requests / max_batch, and
 every result carries a :class:`~repro.tucker.result.RequestTiming` showing
 where its wall-clock went (queue wait vs. shared batched execute).
@@ -415,7 +415,7 @@ class TuckerService:
             # so the no-amortization warning would be misleading.
             if spec.shard is None and not spec_plan.supports_batched_dispatch:
                 warnings.warn(
-                    f"spec {spec.engine=} {spec.pipeline=} "
+                    f"spec {spec.engine=} {spec.precision=} "
                     f"{spec.use_kron_reuse=} cannot share one batched "
                     f"dispatch; its flushes fall back to sequential "
                     f"execution (correct results, no amortization)",

@@ -25,9 +25,6 @@ multi-backend):
                                every_n_sweeps=5, directory="ckpt/job"))
     res = tucker.plan(ft)(coo)              # snapshots as it sweeps
     res = tucker.resume(ft, coo)            # picks up from the latest one
-
-The legacy entrypoints (``repro.core.hooi.hooi_sparse`` / ``hooi_dense`` /
-``tucker_complete_dense``) are deprecation shims over this package.
 """
 from repro.tucker.planning import (
     PlanCache,

@@ -12,9 +12,6 @@ serving loop can assert that via the per-call counters on
 ``TuckerPlan.batch`` is the new serving scenario: pad nnz across a batch of
 same-shape sparse tensors and ``vmap`` the whole multi-sweep program over the
 leading batch axis — one XLA dispatch for k decompositions.
-
-The legacy drivers (``hooi_sparse``/``hooi_dense``/``tucker_complete_dense``)
-are thin deprecation shims over this module.
 """
 from __future__ import annotations
 
@@ -187,11 +184,10 @@ def engine_for_spec(
     prebuilt: Optional[SweepEngine] = None,
     resolved: Optional[str] = None,
 ) -> SweepEngine:
-    """The ONE place a plan's sweep engine comes from — both pipelines
-    ('scan' and 'python') route through here, so ``use_kron_reuse`` follows
-    a single rule: honored on the XLA engine, ignored on Pallas (whose
-    schedule has its own reuse layout), and warned about when a prebuilt
-    engine disagrees with the spec."""
+    """The ONE place a plan's sweep engine comes from, so ``use_kron_reuse``
+    follows a single rule: honored on the XLA engine, ignored on Pallas
+    (whose schedule has its own reuse layout), and warned about when a
+    prebuilt engine disagrees with the spec."""
     if prebuilt is not None:
         if spec.use_kron_reuse and not prebuilt.use_kron_reuse:
             warnings.warn(
@@ -327,7 +323,7 @@ class TuckerPlan:
         return (
             f"TuckerPlan({self.spec.algorithm}, shape={self.spec.shape}, "
             f"ranks={self.spec.ranks}, engine={eng}, "
-            f"pipeline={self.spec.pipeline}, calls={self.stats.calls})"
+            f"calls={self.stats.calls})"
         )
 
     @property
@@ -415,7 +411,7 @@ class TuckerPlan:
         ``vmap``-ed over the leading batch axis. Falls back to k sequential
         calls — same results, k dispatches — for configurations whose
         per-tensor schedules cannot share one program (the Pallas engine,
-        Kron-reuse dedup plans, the legacy python pipeline); ``pad_nnz_to``
+        Kron-reuse dedup plans, bf16 mixed precision); ``pad_nnz_to``
         is irrelevant there (no shared program to stabilize) and ignored —
         EXCEPT on sharded plans, whose per-member shard_map program is also
         shape-keyed on the padded nnz: there each member is padded to
@@ -570,12 +566,6 @@ class TuckerPlan:
         spec, eng = self.spec, self.engine
         if spec.algorithm != "sparse":
             raise ValueError("lower_hlo() supports sparse plans only")
-        if spec.pipeline != "scan":
-            raise ValueError(
-                "only pipeline='scan' plans compile one program; the "
-                "'python' pipeline dispatches per sweep — there is no "
-                "single compiled program to lower"
-            )
         coo = self._check_sparse_input(x)
         ndim = coo.ndim
         work_dtype = jnp.promote_types(coo.values.dtype, jnp.float32)
@@ -759,7 +749,7 @@ class TuckerPlan:
             eng = self.engine.name if self.engine is not None else None
             raise ValueError(
                 f"this plan's batch() runs the sequential fallback "
-                f"(engine={eng!r}, pipeline={spec.pipeline!r}, "
+                f"(engine={eng!r}, precision={spec.precision!r}, "
                 f"use_kron_reuse={spec.use_kron_reuse}, "
                 f"shard={spec.shard is not None}, or non-vmappable keys) — "
                 "there is no shared batched program to lower; lint the "
@@ -826,9 +816,7 @@ class TuckerPlan:
             return self._run_sparse_sharded(coo, factors, xnorm2, pad_nnz_to)
         if pad_nnz_to is not None and int(pad_nnz_to) > coo.nnz:
             coo = coo.pad_to(int(pad_nnz_to))  # explicit zeros: shape-stable
-        if self.spec.pipeline == "scan":
-            return self._run_sparse_scan(coo, factors, xnorm2)
-        return self._run_sparse_python(coo, factors, xnorm2)
+        return self._run_sparse_scan(coo, factors, xnorm2)
 
     def _run_sparse_snapshot(self, coo: Any, key: Any, factors_init: Any,
                              pad_nnz_to: Any, resume_from: Any,
@@ -1137,43 +1125,6 @@ class TuckerPlan:
             schedule_builds=eng.schedule_builds - builds0,
         )
 
-    def _run_sparse_python(self, coo: Any, factors: Any, xnorm2: Any) -> TuckerResult:
-        """The legacy per-sweep driver (benchmark baseline): one dispatch and
-        one blocking host sync per sweep, same math as the scan pipeline."""
-        spec, eng = self.spec, self.engine
-        builds0 = eng.schedule_builds
-        hist: List[float] = []
-        core = None
-        dispatches = 0
-        for _ in range(spec.n_iter):
-            with _obs_span("sweep.dispatch", program="python",
-                           engine=eng.name):
-                if eng.name == "xla" and not eng.use_kron_reuse:
-                    fs, core = _hooi._jitted_sweep(
-                        coo.indices, coo.values, tuple(factors),
-                        shape=spec.shape, ranks=spec.ranks, method=spec.method,
-                    )
-                    factors = list(fs)
-                else:
-                    factors, core = _hooi.sparse_sweep(
-                        coo, factors, spec.ranks, spec.method, engine=eng
-                    )
-                _hooi.SWEEP_DISPATCH_COUNTS.tick((eng.name, "python"))
-                dispatches += 1
-            err = jnp.sqrt(
-                jnp.maximum(xnorm2 - jnp.sum(jnp.square(core)), 0.0)
-            ) / jnp.sqrt(xnorm2)
-            hist.append(float(err))  # blocking host sync — one per sweep
-            if spec.tol and len(hist) > 1 and abs(hist[-2] - hist[-1]) < spec.tol:
-                break
-        return self._result(
-            core, factors, np.asarray(hist),
-            engine=eng.name,
-            dispatches=dispatches,
-            retraces=0,  # tracked for the compiled scan pipeline only
-            schedule_builds=eng.schedule_builds - builds0,
-        )
-
     def _run_sparse_vmapped(self, coos: Any, keys: Any,
                             pad_nnz_to: Any = None) -> List[TuckerResult]:
         spec = self.spec
@@ -1448,8 +1399,7 @@ def plan(spec: TuckerSpec, *, engine: Optional[SweepEngine] = None,
     of ``repro.serve.TuckerService`` never double-build a spec) and LRU-bounded
     when :func:`set_plan_cache_capacity` set a capacity. Passing a prebuilt
     ``engine`` bypasses the cache and wraps that engine directly (its cached
-    device schedules are reused across calls, like handing ``hooi_sparse`` a
-    ``SweepEngine`` did).
+    device schedules are reused across calls).
 
     ``mesh`` (sharded specs only) pins execution to an explicit device mesh
     — its total device count must equal ``spec.shard.num_devices``, and the
@@ -1475,8 +1425,7 @@ def plan(spec: TuckerSpec, *, engine: Optional[SweepEngine] = None,
         )
     else:
         # resolve on every lookup: 'auto'/'pallas' may map differently (and
-        # warn) as backend availability changes — exactly like the legacy
-        # drivers resolved per call.
+        # warn) as backend availability changes.
         key = (spec, resolve_engine(spec.engine))
     return _PLAN_CACHE.get_or_create(key, lambda: TuckerPlan(spec, _resolved=key[1]))
 
@@ -1559,8 +1508,9 @@ def decompose(x: Any, ranks: Sequence[int], *, key: Any = None,
               factors_init: Any = None, **spec_kwargs: Any) -> TuckerResult:
     """One-shot convenience: infer the spec from ``x``, plan (cached), run.
 
-    ``spec_kwargs`` are :class:`TuckerSpec` fields (method, engine, pipeline,
-    n_iter, tol, dtype, use_kron_reuse, algorithm, n_rounds).
+    ``spec_kwargs`` are :class:`TuckerSpec` fields (method, engine, n_iter,
+    tol, dtype, precision, use_kron_reuse, algorithm, n_rounds, shard,
+    snapshot).
     """
     spec = spec_for(x, ranks, **spec_kwargs)
     return plan(spec)(x, key=key, factors_init=factors_init)

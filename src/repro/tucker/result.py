@@ -1,7 +1,6 @@
 """TuckerResult — the unified result type of the plan/execute API.
 
-Subsumes the legacy ``repro.core.hooi.HooiResult`` (it *is* one, by
-subclassing, so every existing consumer keeps working) and adds the serving
+Subclasses ``repro.core.hooi.HooiResult`` and adds the serving
 metadata the ROADMAP's scenarios need: the spec that produced it, the
 compression ratio, the sweep count, and per-call dispatch/retrace/schedule
 counters so a serving loop can assert its steady state is compile-free.
@@ -65,9 +64,9 @@ class TuckerResult(HooiResult):
       compression_ratio: dense storage / Tucker storage (factors included);
         the paper's core-only convention is
         ``repro.core.reconstruct.compression_ratio(..., include_factors=False)``.
-      dispatches: top-level XLA dispatches this call issued (1 for the scan
-        pipeline, ``n_sweeps`` for the legacy python pipeline; 0 where not
-        tracked, e.g. the dense eager driver).
+      dispatches: top-level XLA dispatches this call issued (1 for a fit,
+        one per segment of a snapshot job; 0 where not tracked, e.g. the
+        dense eager driver).
       retraces: traces of the compiled sweep pipeline this call triggered
         (0 on every plan-cache hit — the serving steady state).
       schedule_builds: host-side schedule constructions/uploads this call

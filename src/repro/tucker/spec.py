@@ -2,7 +2,7 @@
 
 A spec captures *everything* that determines the compiled decomposition
 program: tensor shape, multilinear ranks, factor-update method, sweep engine,
-pipeline, sweep budget, tolerance, working dtype, and the Kron-reuse flag.
+sweep budget, tolerance, working dtype, and the Kron-reuse flag.
 Validation happens exactly once, at construction; the spec is hashable so
 ``repro.tucker.plan`` can key its plan cache (and therefore the jit compile
 cache) on it — repeated calls on same-shape tensors hit the cache with zero
@@ -16,7 +16,7 @@ from typing import Any, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.engine import ENGINES
-from repro.core.hooi import PIPELINES, effective_ranks
+from repro.core.hooi import effective_ranks
 
 METHODS = ("svd", "householder", "gram")
 ALGORITHMS = ("sparse", "dense", "complete")
@@ -168,7 +168,7 @@ class SnapshotSpec:
 
 def _canonical_dtype(dtype: Any) -> str:
     """Normalize a dtype spec to a canonical string ("auto" = follow the
-    jax x64 flag at execution time, the legacy drivers' behavior)."""
+    jax x64 flag at execution time)."""
     if dtype is None or dtype == "auto":
         return "auto"
     import jax.numpy as jnp
@@ -188,14 +188,12 @@ class TuckerSpec:
         variant) or 'svd'.
       engine: 'xla', 'pallas' or 'auto' — how the sweep hot loops execute
         (see ``repro.core.engine``).
-      pipeline: 'scan' (whole multi-sweep loop is one XLA program) or
-        'python' (legacy per-sweep driver, the benchmark baseline).
       n_iter: max ALS sweeps per decomposition.
       tol: early-exit threshold on consecutive fit deltas (0 disables). A
         *dynamic* argument of the compiled pipeline — changing it never
         recompiles.
       dtype: working precision of values/factors; "auto" follows the jax
-        x64 flag (legacy behavior).
+        x64 flag.
       precision: sweep compute precision — 'fp32' (full working precision)
         or 'bf16_fp32acc' (bf16 operand loads/multiplies in the Kron and
         TTM kernels with f32 VMEM accumulators; the XLA engine mirrors it
@@ -213,22 +211,21 @@ class TuckerSpec:
       shard: a :class:`ShardSpec` to run the compiled sweep pipeline
         data-parallel over a device mesh (nonzeros sharded, factors
         replicated, one psum per mode per sweep), or ``None`` for
-        single-device execution. Requires the sparse algorithm on the scan
-        pipeline with the plain XLA engine (no Kron-reuse — its dedup plan
-        is a per-tensor host artifact that cannot shard).
+        single-device execution. Requires the sparse algorithm with the
+        plain XLA engine (no Kron-reuse — its dedup plan is a per-tensor
+        host artifact that cannot shard).
       snapshot: a :class:`SnapshotSpec` to run the compiled sweep pipeline
         in chunked segments with the carry checkpointed at each interval
         (resumable via ``tucker.resume``), or ``None`` for the one-dispatch
-        fire-and-forget run. Requires the sparse algorithm on the scan
-        pipeline; composes with ``shard`` (elastic resume onto a different
-        device count) and with every engine.
+        fire-and-forget run. Requires the sparse algorithm; composes with
+        ``shard`` (elastic resume onto a different device count) and with
+        every engine.
     """
 
     shape: Tuple[int, ...]
     ranks: Tuple[int, ...]
     method: str = "householder"
     engine: str = "auto"
-    pipeline: str = "scan"
     n_iter: int = 5
     tol: float = 0.0
     dtype: str = "auto"
@@ -256,10 +253,6 @@ class TuckerSpec:
             raise ValueError(f"method must be one of {METHODS}, got {self.method!r}")
         if self.engine not in ENGINES:
             raise ValueError(f"engine must be one of {ENGINES}, got {self.engine!r}")
-        if self.pipeline not in PIPELINES:
-            raise ValueError(
-                f"pipeline must be one of {PIPELINES}, got {self.pipeline!r}"
-            )
         if self.algorithm not in ALGORITHMS:
             raise ValueError(
                 f"algorithm must be one of {ALGORITHMS}, got {self.algorithm!r}"
@@ -291,11 +284,6 @@ class TuckerSpec:
                     f"shard requires algorithm='sparse' (only COO nonzeros "
                     f"have an nnz axis to shard), got {self.algorithm!r}"
                 )
-            if self.pipeline != "scan":
-                raise ValueError(
-                    "shard requires pipeline='scan': the sharded path IS the "
-                    "compiled scan-over-sweeps program wrapped in shard_map"
-                )
             if self.engine == "pallas":
                 raise ValueError(
                     "shard requires the XLA engine: the Pallas kernels do "
@@ -325,11 +313,6 @@ class TuckerSpec:
                     f"compiled sweep pipeline has a resumable carry), got "
                     f"{self.algorithm!r}"
                 )
-            if self.pipeline != "scan":
-                raise ValueError(
-                    "snapshot requires pipeline='scan': the snapshot layer "
-                    "IS the compiled scan program run in resumable segments"
-                )
         object.__setattr__(self, "shape", shape)
         object.__setattr__(self, "ranks", ranks)
         object.__setattr__(self, "n_iter", int(self.n_iter))
@@ -345,18 +328,17 @@ class TuckerSpec:
     def supports_batched_dispatch(self) -> bool:
         """True when plans for this spec can vmap k tensors into ONE XLA
         dispatch (``TuckerPlan.batch``'s fast path, and the micro-batching
-        contract ``repro.serve.TuckerService`` schedules around): the compiled
-        scan pipeline over sparse COO input, without the Kron-reuse dedup
-        (whose per-tensor plan arrays have data-dependent sizes and cannot
-        share one batched program). Sharded specs are excluded too: their one
-        program already spans the mesh, so a batch runs them sequentially —
-        still one dispatch per member. Snapshot specs are excluded as well:
-        a snapshot job is one long-running fit bound to its own checkpoint
-        directory, not a batch member. The engine must additionally *resolve*
+        contract ``repro.serve.TuckerService`` schedules around): sparse COO
+        input, without the Kron-reuse dedup (whose per-tensor plan arrays
+        have data-dependent sizes and cannot share one batched program).
+        Sharded specs are excluded too: their one program already spans the
+        mesh, so a batch runs them sequentially — still one dispatch per
+        member. Snapshot specs are excluded as well: a snapshot job is one
+        long-running fit bound to its own checkpoint directory, not a batch
+        member. The engine must additionally *resolve*
         to 'xla' — that happens at plan level, where resolution lives."""
         return (
             self.algorithm == "sparse"
-            and self.pipeline == "scan"
             and not self.use_kron_reuse
             and self.shard is None
             and self.snapshot is None
@@ -365,7 +347,7 @@ class TuckerSpec:
 
     def resolved_dtype(self) -> Any:
         """The concrete working dtype, or ``None`` for "auto" (follow the
-        jax x64 flag at execution time, like the legacy drivers)."""
+        jax x64 flag at execution time)."""
         if self.dtype == "auto":
             return None
         import jax.numpy as jnp

@@ -1,6 +1,6 @@
 """JAX's persistent compilation cache, placed from outside the library.
 
-Entry points (``chip_smoke.py``, the benchmark harness) call
+Entry points (the benchmark harness) call
 :func:`enable_compile_cache` once before their first compile; library code
 and tests never do. The cache key includes the directory, so the default is
 a fixed path inside the checkout rather than anything temporary.

@@ -374,17 +374,6 @@ def test_batched_cell_in_default_matrix():
     assert cells["xla/batched/fp32"].batch > 0
 
 
-def test_python_pipeline_has_no_program(coo):
-    plan = TuckerPlan(
-        TuckerSpec(
-            shape=SHAPE, ranks=RANKS, method="gram", engine="xla",
-            pipeline="python",
-        )
-    )
-    with pytest.raises(ValueError, match="no single compiled program"):
-        plan.lower_hlo(coo)
-
-
 def test_baseline_suppression_roundtrip(tmp_path):
     f1 = Finding("transfer", "error", "cell/comp", "an outfeed happened")
     f2 = Finding("donation", "error", "cell/param2", "donation dropped")

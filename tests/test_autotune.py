@@ -299,11 +299,3 @@ def test_plan_analyze_reports_roofline_fields():
     )
     assert s["engine"] == "xla" and s["precision"] == "fp32"
     assert s["fuse_core"] is False and s["tuned_blocks"] is None
-
-
-def test_plan_analyze_rejects_non_scan_plans():
-    spec = tucker.TuckerSpec(shape=(10, 8, 6), ranks=(2, 2, 2),
-                             pipeline="python")
-    coo = random_sparse_tensor((10, 8, 6), 0.05, seed=4)
-    with pytest.raises(ValueError, match="scan"):
-        tucker.plan(spec).analyze(coo)

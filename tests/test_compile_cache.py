@@ -1,7 +1,5 @@
-"""``chip_smoke.py`` must refuse to run anywhere but on a TPU: without one it
-exits nonzero before any phase and prints no verdict line. The compile cache
-its entry points enable lives where ``$JAX_COMPILATION_CACHE_DIR`` says, else
-at the fixed ``<repo>/.jax_cache``."""
+"""The compile cache the entry points enable lives where
+``$JAX_COMPILATION_CACHE_DIR`` says, else at the fixed ``<repo>/.jax_cache``."""
 import os
 import subprocess
 import sys
@@ -9,17 +7,6 @@ import sys
 import pytest
 
 ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
-
-
-def test_chip_smoke_refuses_cpu():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [sys.executable, "chip_smoke.py"], cwd=ROOT, env=env,
-        capture_output=True, text=True, timeout=300,
-    )
-    assert out.returncode != 0
-    assert '"ok"' not in out.stdout
-    assert "needs a TPU" in out.stderr
 
 
 @pytest.mark.parametrize("from_env", [True, False])

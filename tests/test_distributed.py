@@ -25,41 +25,6 @@ def _run(code: str, ndev: int = 8) -> str:
     return out.stdout
 
 
-def test_distributed_hooi_shim_matches_single_device():
-    """The retired eager driver is a deprecation shim over the planned
-    sharded pipeline: calling it must warn DeprecationWarning exactly once,
-    flatten the mesh's nnz axes into an equivalent shard count, and still
-    match the single-device reference. This is the deprecation-warning
-    regression test for the old eager-driver surface."""
-    got = _run("""
-        import warnings
-        import jax, numpy as np, jax.numpy as jnp
-        from jax.sharding import AxisType
-        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
-        from repro.sparse.generators import low_rank_sparse_tensor
-        from repro import tucker
-        from repro.core.distributed import hooi_sparse_distributed
-        coo, _ = low_rank_sparse_tensor((24, 20, 16), (3, 2, 2), 0.15, seed=0)
-        a = tucker.decompose(coo, (3, 2, 2), n_iter=3, method="gram",
-                             engine="xla")
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            b = hooi_sparse_distributed(coo, (3, 2, 2), mesh, n_iter=3,
-                                        method="gram",
-                                        nnz_axes=("data", "model"))
-        n_dep = sum(issubclass(x.category, DeprecationWarning) for x in w)
-        # the shim delegated to the planned path: one shard_map dispatch
-        # over an 8-shard nnz mesh, with the shard counters attached
-        print(float(a.rel_error), float(b.rel_error), n_dep,
-              b.dispatches, b.shard_imbalance is not None)
-    """)
-    a, b, n_dep, dispatches, has_imbalance = got.split()
-    assert abs(float(a) - float(b)) < 2e-3
-    assert int(n_dep) == 1
-    assert int(dispatches) == 1
-    assert has_imbalance == "True"
-
-
 def test_train_step_shards_on_multi_device():
     got = _run("""
         import jax, numpy as np, jax.numpy as jnp

@@ -3,7 +3,7 @@
 Contract: every available engine must produce, for every mode, an unfolding
 Y_(n) within tolerance of the dense ``ttm_chain`` oracle — across tensor
 orders, dtypes, ranks, and pathological sparsity patterns — and every engine
-must drive ``hooi_sparse`` to the same fit. Any new engine (or any change to
+must drive a sparse HOOI fit (``tucker.decompose``) to the same result. Any new engine (or any change to
 the Pallas kernels / layouts) has to pass this file before it can ship.
 """
 
@@ -12,19 +12,12 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import tucker
 from repro.core import engine as E
 from repro.core.coo import SparseCOO, unfold_dense
-from repro.core.hooi import hooi_sparse
 from repro.core.ttm import ttm_chain, ttm_unfolded
 from repro.sparse.generators import low_rank_sparse_tensor, random_sparse_tensor
 from repro.sparse.layout import build_mode_layout, layout_padding_fraction
-
-# engine parity is asserted through the legacy hooi_sparse shim on purpose
-# (the acceptance criterion predates repro.tucker) — opt back out of the
-# repo-wide warning-as-error promotion for exactly that message.
-pytestmark = pytest.mark.filterwarnings(
-    "default:hooi_sparse is deprecated"
-)
 
 ENGINES = E.available_engines()
 RNG = np.random.default_rng(0)
@@ -121,7 +114,7 @@ def test_engines_nnz_not_block_multiple():
 
 
 # ---------------------------------------------------------------------------
-# hooi_sparse fit parity across engines (acceptance criterion: >= 3 tensors).
+# sparse HOOI fit parity across engines (acceptance criterion: >= 3 tensors).
 # ---------------------------------------------------------------------------
 
 
@@ -139,9 +132,9 @@ def test_hooi_sparse_engine_fit_parity(tensor_id):
     else:
         coo = random_sparse_tensor((14, 12, 10, 8), 0.01, seed=3)
         ranks = (3, 3, 2, 2)
-    ref = hooi_sparse(coo, ranks, n_iter=3, method="gram", engine="xla")
+    ref = tucker.decompose(coo, ranks, n_iter=3, method="gram", engine="xla")
     for name in ENGINES:
-        res = hooi_sparse(coo, ranks, n_iter=3, method="gram", engine=name)
+        res = tucker.decompose(coo, ranks, n_iter=3, method="gram", engine=name)
         assert res.engine == name
         assert abs(float(res.rel_error) - float(ref.rel_error)) < 1e-4, name
         np.testing.assert_allclose(
@@ -151,7 +144,7 @@ def test_hooi_sparse_engine_fit_parity(tensor_id):
 
 def test_hooi_sparse_engine_auto_resolves():
     coo = random_sparse_tensor((15, 12, 10), 0.05, seed=5)
-    res = hooi_sparse(coo, (3, 3, 2), n_iter=1, method="gram", engine="auto")
+    res = tucker.decompose(coo, (3, 3, 2), n_iter=1, method="gram", engine="auto")
     want = "pallas" if jax.default_backend() == "tpu" else "xla"
     assert res.engine == want
 

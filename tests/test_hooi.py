@@ -1,5 +1,5 @@
-"""HOOI via the plan/execute front-end: Alg. 1 vs Alg. 2, QRP-vs-SVD
-accuracy (paper Table II), and the legacy shims' bit-parity with the API."""
+"""HOOI via the plan/execute front-end: Alg. 1 vs Alg. 2 and QRP-vs-SVD
+accuracy (paper Table II)."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -53,8 +53,8 @@ def test_qrp_matches_svd():
             errs[method] = float(
                 tucker.decompose(xn, (8, 8, 8), n_iter=3, method=method).rel_error
             )
-        # same accuracy scale (the paper's exact-agreement claim at the
-        # 1e-9 error floor is reproduced in float64 by benchmarks/table2)
+        # same accuracy scale (the paper's exact agreement is at a 1e-9
+        # error floor, which needs float64)
         assert errs["householder"] == pytest.approx(errs["svd"], rel=0.15)
         assert errs["gram"] == pytest.approx(errs["svd"], rel=0.15)
 
@@ -100,39 +100,3 @@ def test_compression_ratio_paper_angiogram():
     assert compression_ratio((130, 150), (30, 35), include_factors=False) \
         == pytest.approx(18.57, rel=0.01)
     assert compression_ratio((130, 150), (30, 35)) == pytest.approx(1.91, rel=0.02)
-
-
-# ---------------------------------------------------------------------------
-# Legacy deprecation shims: bit-parity with the plan API, and they warn.
-# ---------------------------------------------------------------------------
-
-
-def test_hooi_sparse_shim_bit_identical_to_plan():
-    from repro.core.hooi import hooi_sparse
-
-    coo = random_sparse_tensor((18, 14, 10), 0.05, seed=12)
-    want = tucker.decompose(coo, (3, 3, 2), n_iter=3, method="gram", engine="xla")
-    with pytest.warns(DeprecationWarning, match="hooi_sparse is deprecated"):
-        got = hooi_sparse(coo, (3, 3, 2), n_iter=3, method="gram", engine="xla")
-    assert isinstance(got, tucker.TuckerResult)  # subsumes HooiResult
-    np.testing.assert_array_equal(np.asarray(got.core), np.asarray(want.core))
-    for a, b in zip(got.factors, want.factors):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_array_equal(got.fit_history, want.fit_history)
-
-
-def test_dense_and_complete_shims_match_plan():
-    from repro.core.hooi import hooi_dense, tucker_complete_dense
-
-    x = jnp.asarray(_lowrank_dense((12, 10, 8), (3, 3, 2), seed=7))
-    want = tucker.decompose(x, (3, 3, 2), n_iter=2, method="svd")
-    with pytest.warns(DeprecationWarning, match="hooi_dense is deprecated"):
-        got = hooi_dense(x, (3, 3, 2), n_iter=2, method="svd")
-    np.testing.assert_array_equal(np.asarray(got.core), np.asarray(want.core))
-
-    coo, _ = low_rank_sparse_tensor((12, 12, 12), (2, 2, 2), 0.3, seed=8)
-    want = tucker.decompose(coo, (2, 2, 2), algorithm="complete", n_rounds=2,
-                            n_iter=1, method="gram")
-    with pytest.warns(DeprecationWarning, match="tucker_complete_dense"):
-        got = tucker_complete_dense(coo, (2, 2, 2), n_rounds=2, n_iter=1)
-    np.testing.assert_array_equal(np.asarray(got.core), np.asarray(want.core))

@@ -69,15 +69,6 @@ def test_bf16_engines_agree_with_each_other():
     assert abs(fits["xla"] - fits["pallas"]) < 1e-3
 
 
-@pytest.mark.parametrize("engine", ENGINES)
-def test_bf16_python_pipeline_parity(engine):
-    """The precision axis follows the spec through BOTH pipelines."""
-    coo = random_sparse_tensor((16, 12, 10), 0.05, seed=2)
-    scan = _decompose(coo, engine, "bf16_fp32acc", pipeline="scan")
-    legacy = _decompose(coo, engine, "bf16_fp32acc", pipeline="python")
-    assert abs(scan.rel_error - legacy.rel_error) < 1e-3
-
-
 def test_bf16_batch_falls_back_sequentially():
     """batch() on a bf16 spec must not take the fp32-only vmapped program —
     it falls back to sequential calls with per-call-identical results."""

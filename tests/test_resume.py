@@ -157,8 +157,6 @@ def test_tucker_spec_snapshot_constraints(tmp_path):
 
     snap = tucker.SnapshotSpec(every_n_sweeps=2, directory=str(tmp_path))
     kw = dict(shape=SHAPE, ranks=RANKS, snapshot=snap)
-    with pytest.raises(ValueError, match="pipeline='scan'"):
-        tucker.TuckerSpec(pipeline="python", **kw)
     with pytest.raises(ValueError, match="sparse"):
         tucker.TuckerSpec(algorithm="dense", **kw)
     # a snapshot job is one long-running fit: never vmap-batched

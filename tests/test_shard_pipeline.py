@@ -240,8 +240,6 @@ def test_tucker_spec_shard_constraints():
 
     shard = tucker.ShardSpec(num_devices=1)
     kw = dict(shape=(8, 8, 8), ranks=(2, 2, 2), shard=shard)
-    with pytest.raises(ValueError, match="pipeline='scan'"):
-        tucker.TuckerSpec(pipeline="python", **kw)
     with pytest.raises(ValueError, match="XLA engine"):
         tucker.TuckerSpec(engine="pallas", **kw)
     with pytest.raises(ValueError, match="kron_reuse"):

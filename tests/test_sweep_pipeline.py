@@ -1,20 +1,24 @@
-"""Regression harness for the compiled scan-over-sweeps pipeline (core.hooi).
+"""Regression harness for the compiled scan-over-sweeps program (core.hooi).
 
 Four contracts:
 
-1. *Fit parity*: the scan pipeline is bit-compatible (to float noise) with
-   the legacy per-sweep Python driver — same factors math, same fit history,
-   same ``tol`` early-exit sweep — on every available engine.
-2. *No retrace*: a second ``hooi_sparse`` call on a same-shape tensor must hit
-   the compiled-sweep jit cache (zero new traces) and dispatch exactly one
-   XLA program regardless of ``n_iter``.
+1. *Early exit*: the ``tol`` early exit stops at the first sweep where the
+   independent reference's fit history (``bench/reference.py``) changes by
+   less than ``tol``, with the same per-sweep errors, on every engine. (Fit
+   parity of every compiled program with that reference is
+   ``tests/test_reference_parity.py``.)
+2. *No retrace*: a second call on a same-shape tensor must hit the
+   compiled-sweep jit cache (zero new traces) and dispatch exactly one XLA
+   program regardless of ``n_iter``.
 3. *Single transfer*: the fit history crosses device->host exactly once per
-   call (the per-sweep blocking ``float(err)`` sync is gone).
+   call.
 4. *Schedules*: the vectorized ``build_schedule`` matches the original
    per-row-block reference loop, device schedules upload once, and a rebound
    engine does not pin the previous tensor's indices.
 """
 import gc
+import os
+import sys
 import weakref
 
 import numpy as np
@@ -22,18 +26,18 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import tucker
 from repro.core import engine as E
 from repro.core import hooi
-from repro.core.hooi import hooi_sparse
 from repro.sparse.generators import random_sparse_tensor
 from repro.sparse.layout import DeviceSchedule, build_schedule
 
-# this file deliberately drives the legacy hooi_sparse shim (python-vs-scan
-# parity on the OLD surface) — opt back out of the repo-wide
-# warning-as-error promotion for exactly that deprecation message.
-pytestmark = pytest.mark.filterwarnings(
-    "default:hooi_sparse is deprecated"
-)
+# the plain reference is imported as the ``bench`` package from the repo root
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from bench import reference  # noqa: E402
 
 ENGINES = E.available_engines()
 
@@ -42,154 +46,111 @@ def _total_traces():
     return sum(hooi.SWEEP_TRACE_COUNTS.values())
 
 
-def _dispatches(engine, pipeline):
-    return hooi.SWEEP_DISPATCH_COUNTS[(engine, pipeline)]
+def _dispatches(engine):
+    return hooi.SWEEP_DISPATCH_COUNTS[(engine, "scan")]
 
 
-# ---------------------------------------------------------------------------
-# 1. Fit parity: scan pipeline == legacy python driver.
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("engine", ENGINES)
-@pytest.mark.parametrize("method", ["householder", "gram"])
-def test_scan_matches_python_pipeline(engine, method):
-    coo = random_sparse_tensor((24, 20, 16), 0.04, seed=31)
-    ranks = (4, 3, 2)
-    a = hooi_sparse(coo, ranks, n_iter=3, method=method, engine=engine,
-                    pipeline="python")
-    b = hooi_sparse(coo, ranks, n_iter=3, method=method, engine=engine,
-                    pipeline="scan")
-    assert a.engine == b.engine == engine
-    assert len(a.fit_history) == len(b.fit_history)
-    np.testing.assert_allclose(a.fit_history, b.fit_history, atol=1e-5)
-    np.testing.assert_allclose(
-        np.asarray(a.core), np.asarray(b.core), rtol=1e-4, atol=1e-4
-    )
-    for fa, fb in zip(a.factors, b.factors):
-        np.testing.assert_allclose(np.asarray(fa), np.asarray(fb), atol=1e-4)
-
-
-def test_scan_matches_python_pipeline_kron_reuse():
-    coo = random_sparse_tensor((20, 18, 14), 0.05, seed=32)
-    a = hooi_sparse(coo, (3, 3, 2), n_iter=3, method="gram", engine="xla",
-                    use_kron_reuse=True, pipeline="python")
-    b = hooi_sparse(coo, (3, 3, 2), n_iter=3, method="gram", engine="xla",
-                    use_kron_reuse=True, pipeline="scan")
-    np.testing.assert_allclose(a.fit_history, b.fit_history, atol=1e-5)
-
-
-@pytest.mark.parametrize("shape,ranks", [((10, 9, 8, 7), (3, 2, 2, 2)),
-                                         ((30, 20), (4, 3))])
-def test_scan_matches_python_other_orders(shape, ranks):
-    coo = random_sparse_tensor(shape, 0.02, seed=33)
-    for engine in ENGINES:
-        a = hooi_sparse(coo, ranks, n_iter=2, method="gram", engine=engine,
-                        pipeline="python")
-        b = hooi_sparse(coo, ranks, n_iter=2, method="gram", engine=engine,
-                        pipeline="scan")
-        np.testing.assert_allclose(a.fit_history, b.fit_history, atol=1e-5)
+def _fit(coo, ranks, n_iter, engine, tol=0.0, method="gram"):
+    return tucker.decompose(coo, ranks, n_iter=n_iter, method=method,
+                            engine=engine, tol=tol)
 
 
 def test_unknown_pipeline_raises():
+    """The spec refuses what no program runs: zero sweeps, an unknown
+    engine."""
     coo = random_sparse_tensor((8, 8, 8), 0.05, seed=34)
-    with pytest.raises(ValueError, match="pipeline"):
-        hooi_sparse(coo, (2, 2, 2), n_iter=1, pipeline="fpga")
     with pytest.raises(ValueError, match="n_iter"):
-        hooi_sparse(coo, (2, 2, 2), n_iter=0)
+        _fit(coo, (2, 2, 2), 0, "xla")
+    with pytest.raises(ValueError, match="engine"):
+        _fit(coo, (2, 2, 2), 1, "fpga")
 
 
 # ---------------------------------------------------------------------------
-# 2. tol early-exit parity: same stop sweep, same history, both engines.
+# 1. tol early exit: the reference's stop sweep and errors, both engines.
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("engine", ENGINES)
 def test_tol_early_exit_parity(engine):
     coo = random_sparse_tensor((25, 20, 15), 0.05, seed=3)
-    tol = 1e-3
-    a = hooi_sparse(coo, (3, 3, 2), n_iter=10, method="gram", tol=tol,
-                    engine=engine, pipeline="python")
-    b = hooi_sparse(coo, (3, 3, 2), n_iter=10, method="gram", tol=tol,
-                    engine=engine, pipeline="scan")
-    # the early exit actually fired (otherwise this test checks nothing) ...
-    assert len(a.fit_history) < 10
-    # ... at the same sweep, with the same per-sweep errors.
-    assert len(a.fit_history) == len(b.fit_history)
-    np.testing.assert_allclose(a.fit_history, b.fit_history, atol=1e-5)
+    ranks, tol, n_iter = (3, 3, 2), 1e-3, 10
+    key = jax.random.PRNGKey(0)
+    x = reference.Tensor(np.asarray(coo.indices), np.asarray(coo.values),
+                         coo.shape, block=4096)
+    _, _, ref = reference.hooi(x, ranks, n_iter, key)
+    # the reference stops at the first sweep whose error moved less than tol
+    stop = next(s for s in range(2, n_iter + 1)
+                if abs(ref[s - 1] - ref[s - 2]) < tol)
+    # the early exit fires (otherwise this test checks nothing), and the
+    # crossing is clear of tol by far more than the 1e-5 fit tolerance
+    assert stop < n_iter
+    assert all(abs(abs(ref[s - 1] - ref[s - 2]) - tol) > 1e-4
+               for s in range(2, stop + 1))
+    res = tucker.plan(tucker.TuckerSpec(
+        shape=coo.shape, ranks=ranks, method="gram", engine=engine,
+        n_iter=n_iter, tol=tol))(coo, key=key)
+    assert res.engine == engine
+    assert len(res.fit_history) == stop
+    np.testing.assert_allclose(res.fit_history, ref[:stop], atol=1e-5)
 
 
 def test_tol_zero_runs_all_sweeps():
     coo = random_sparse_tensor((15, 12, 10), 0.05, seed=4)
-    res = hooi_sparse(coo, (3, 3, 2), n_iter=4, method="gram", tol=0.0,
-                      pipeline="scan", engine="xla")
+    res = _fit(coo, (3, 3, 2), 4, "xla", tol=0.0)
     assert len(res.fit_history) == 4
     # the emitted history contains real errors, not skip sentinels
     assert (res.fit_history >= 0).all()
 
 
 def test_tol_change_does_not_retrace():
-    """tol is a dynamic argument of the compiled pipeline — sweeping it (e.g.
+    """tol is a dynamic argument of the compiled program — sweeping it (e.g.
     a tolerance study) must not recompile."""
     coo = random_sparse_tensor((15, 12, 10), 0.05, seed=5)
-    hooi_sparse(coo, (3, 3, 2), n_iter=4, method="gram", tol=1e-2,
-                pipeline="scan", engine="xla")
+    _fit(coo, (3, 3, 2), 4, "xla", tol=1e-2)
     before = _total_traces()
     for tol in (0.0, 1e-5, 0.3):
-        hooi_sparse(coo, (3, 3, 2), n_iter=4, method="gram", tol=tol,
-                    pipeline="scan", engine="xla")
+        _fit(coo, (3, 3, 2), 4, "xla", tol=tol)
     assert _total_traces() == before
 
 
 # ---------------------------------------------------------------------------
-# 3. No-retrace + dispatch-count regression (the perf contract).
+# 2. No-retrace + dispatch-count regression (the perf contract).
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("engine", ENGINES)
 def test_no_retrace_on_same_shape(engine):
-    """Two same-shape tensors: the second hooi_sparse call must hit the
-    compiled sweep's jit cache — zero new traces — and cost exactly one
-    dispatch, independent of n_iter."""
+    """Two same-shape tensors: the second call must hit the compiled
+    sweep's jit cache — zero new traces — and cost exactly one dispatch,
+    independent of n_iter."""
     shape, ranks, n_iter = (20, 16, 12), (3, 3, 2), 4
     coo_a = random_sparse_tensor(shape, 0.05, seed=41)
     coo_b = random_sparse_tensor(shape, 0.05, seed=42)
-    hooi_sparse(coo_a, ranks, n_iter=n_iter, method="gram", engine=engine,
-                pipeline="scan")  # warm (may trace)
+    _fit(coo_a, ranks, n_iter, engine)  # warm (may trace)
     traces = _total_traces()
     cache = hooi._scan_sweeps._cache_size()
-    d0 = _dispatches(engine, "scan")
-    res = hooi_sparse(coo_b, ranks, n_iter=n_iter, method="gram", engine=engine,
-                      pipeline="scan")
+    d0 = _dispatches(engine)
+    res = _fit(coo_b, ranks, n_iter, engine)
     assert _total_traces() == traces, "same-shape call retraced the pipeline"
     assert hooi._scan_sweeps._cache_size() == cache
-    assert _dispatches(engine, "scan") - d0 == 1  # 1 dispatch per call, not per sweep
+    assert _dispatches(engine) - d0 == 1  # 1 dispatch per call, not per sweep
     assert len(res.fit_history) == n_iter
 
 
-def test_python_pipeline_dispatches_per_sweep():
-    """The legacy driver's dispatch count scales with n_iter — the structural
-    contrast the scan pipeline removes (and sweep_bench.py reports)."""
-    coo = random_sparse_tensor((15, 12, 10), 0.05, seed=43)
-    d0 = _dispatches("xla", "python")
-    hooi_sparse(coo, (3, 3, 2), n_iter=3, method="gram", engine="xla",
-                pipeline="python")
-    assert _dispatches("xla", "python") - d0 == 3
-
-
 # ---------------------------------------------------------------------------
-# 4. Single device->host transfer for the fit history.
+# 3. Single device->host transfer for the fit history.
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("engine", ENGINES)
 def test_single_history_transfer(engine, monkeypatch):
-    """The scan pipeline fetches the fit history with exactly one device_get;
+    """The scan program fetches the fit history with exactly one device_get;
     nothing else in the call forces a device->host sync."""
     coo = random_sparse_tensor((20, 16, 12), 0.05, seed=44)
-    eng = E.make_engine(engine)
-    hooi_sparse(coo, (3, 3, 2), n_iter=5, method="gram", engine=eng,
-                pipeline="scan")  # warm: schedules + compile
+    spec = tucker.TuckerSpec(shape=coo.shape, ranks=(3, 3, 2), method="gram",
+                             engine=engine, n_iter=5)
+    p = tucker.plan(spec, engine=E.make_engine(engine))
+    p(coo)  # warm: schedules + compile
     calls = []
 
     def counting_fetch(x):
@@ -197,14 +158,13 @@ def test_single_history_transfer(engine, monkeypatch):
         return jax.device_get(x)
 
     monkeypatch.setattr(hooi, "_fetch_history", counting_fetch)
-    res = hooi_sparse(coo, (3, 3, 2), n_iter=5, method="gram", engine=eng,
-                      pipeline="scan")
+    res = p(coo)
     assert len(calls) == 1
     assert len(res.fit_history) == 5
 
 
 # ---------------------------------------------------------------------------
-# 5. Schedules: vectorized builder, one-time upload, no tensor pinning.
+# 4. Schedules: vectorized builder, one-time upload, no tensor pinning.
 # ---------------------------------------------------------------------------
 
 
@@ -278,7 +238,7 @@ def test_xla_engine_needs_no_schedule():
 
 
 # ---------------------------------------------------------------------------
-# 6. TuckerPlan reuse: the serving steady state is zero retraces AND zero
+# 5. TuckerPlan reuse: the serving steady state is zero retraces AND zero
 #    schedule rebuilds (per-call counters on TuckerResult / SweepEngine).
 # ---------------------------------------------------------------------------
 
@@ -289,8 +249,6 @@ def test_plan_reuse_zero_retrace_zero_schedule_rebuilds(engine):
     zero new traces of the compiled sweep and zero schedule builds/uploads.
     A DISTINCT same-shape tensor still retraces nothing (schedules alone may
     rebuild — they are per-tensor data)."""
-    from repro import tucker
-
     spec = tucker.TuckerSpec(shape=(20, 16, 12), ranks=(3, 3, 2),
                              method="gram", engine=engine, n_iter=3)
     p = tucker.plan(spec)
@@ -316,8 +274,6 @@ def test_plan_reuse_zero_retrace_zero_schedule_rebuilds(engine):
 def test_plan_reuse_kron_schedules_cached():
     """Kron-reuse dedup plans are per-tensor schedules too: cached on the
     plan's engine, rebuilt only when the tensor changes."""
-    from repro import tucker
-
     spec = tucker.TuckerSpec(shape=(16, 14, 12), ranks=(3, 3, 2),
                              method="gram", engine="xla", n_iter=2,
                              use_kron_reuse=True)
@@ -351,7 +307,7 @@ def test_rebound_engine_does_not_pin_old_tensor():
 
 
 # ---------------------------------------------------------------------------
-# 7. The nonzeros are ordered once per program call: on the pallas engine
+# 6. The nonzeros are ordered once per program call: on the pallas engine
 #    the gathers of ``indices`` and ``values`` into each mode's schedule
 #    order run before the sweep loop, which reads only their results.
 # ---------------------------------------------------------------------------
@@ -400,7 +356,6 @@ def test_pallas_program_reads_each_calls_values():
     """The sorted values are made from the values of the call: a re-fit of
     the same nonzeros with new counts on a warm plan gives what a fresh plan
     gives on them."""
-    from repro import tucker
     from repro.core.coo import SparseCOO
 
     spec = tucker.TuckerSpec(shape=(14, 12, 10), ranks=(3, 2, 2), method="householder",
@@ -416,37 +371,28 @@ def test_pallas_program_reads_each_calls_values():
     np.testing.assert_array_equal(np.asarray(res.core), np.asarray(fresh.core))
 
 
-@pytest.mark.parametrize("run", ["python", "segment2", "segment5"])
+@pytest.mark.parametrize("run", ["segment2", "segment5"])
 def test_pallas_pipelines_give_the_same_decomposition(run, tmp_path):
-    """The scan program, the snapshot segment program and the per-sweep
-    driver run the same order gathers and kernels on one tensor."""
-    from repro import tucker
-
+    """The scan program and the snapshot segment program run the same order
+    gathers and kernels on one tensor."""
     kw = dict(shape=(14, 12, 10), ranks=(3, 2, 2), method="householder", engine="pallas",
               n_iter=5, tol=0.0)
     coo = random_sparse_tensor(kw["shape"], 0.1, seed=64)
     want = tucker.plan(tucker.TuckerSpec(**kw))(coo)
-    if run == "python":
-        spec = tucker.TuckerSpec(pipeline="python", **kw)
-    else:
-        every = int(run[len("segment"):])
-        spec = tucker.TuckerSpec(snapshot=tucker.SnapshotSpec(
-            every_n_sweeps=every, directory=str(tmp_path)), **kw)
+    every = int(run[len("segment"):])
+    spec = tucker.TuckerSpec(snapshot=tucker.SnapshotSpec(
+        every_n_sweeps=every, directory=str(tmp_path)), **kw)
     got = tucker.plan(spec)(coo)
     np.testing.assert_array_equal(np.asarray(got.core), np.asarray(want.core))
     for a, b in zip(got.factors, want.factors):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    if run == "python":  # its fit is eager float32 ops, rounding apart from the fused ones
-        np.testing.assert_allclose(got.fit_history, want.fit_history, rtol=1e-6)
-    else:
-        np.testing.assert_array_equal(got.fit_history, want.fit_history)
+    np.testing.assert_array_equal(got.fit_history, want.fit_history)
 
 
 @pytest.mark.parametrize("fuse_core", [False, True])
 def test_pallas_program_on_an_empty_tensor(fuse_core):
     """With no nonzeros there is nothing to order: every unfolding the
     program builds is the zero unfolding, so the core is zero."""
-    from repro import tucker
     from repro.core.coo import SparseCOO
 
     coo = SparseCOO.from_parts(np.zeros((0, 3), np.int32), np.zeros((0,), np.float32),

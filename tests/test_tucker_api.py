@@ -3,15 +3,13 @@
 Acceptance criteria under test (ISSUE 3):
 
 * a ``TuckerPlan`` called twice on distinct same-shape/same-spec tensors
-  shows 0 retraces and is bit-identical to ``hooi_sparse`` on both engines;
+  shows 0 retraces and is bit-identical to a fresh ``decompose`` on both
+  engines;
 * ``TuckerPlan.batch`` over k tensors matches k sequential calls;
-* ``use_kron_reuse`` follows one rule on BOTH pipelines (the engine comes
-  from one construction helper) — regression for the old python-pipeline
-  inconsistency;
+* ``use_kron_reuse`` follows one rule (the engine comes from one
+  construction helper);
 * ``TuckerResult`` survives an empty fit history (no ``hist[-1]`` crash).
 """
-import warnings
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -42,8 +40,6 @@ def _spec(shape=(20, 16, 12), ranks=(3, 3, 2), **kw):
 
 
 def test_spec_validation_errors():
-    with pytest.raises(ValueError, match="pipeline"):
-        _spec(pipeline="fpga")
     with pytest.raises(ValueError, match="engine"):
         _spec(engine="fpga")
     with pytest.raises(ValueError, match="method"):
@@ -151,12 +147,12 @@ def test_plan_rejects_wrong_shape_and_type():
 
 # ---------------------------------------------------------------------------
 # Acceptance: zero retraces across distinct same-shape tensors, and
-# bit-identical results to the hooi_sparse shim — on every engine.
+# bit-identical results to a fresh plan's — on every engine.
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("engine", ENGINES)
-def test_plan_zero_retrace_and_bit_parity_with_hooi_sparse(engine):
+def test_plan_zero_retrace_and_bit_parity_with_fresh_decompose(engine):
     spec = _spec(engine=engine)
     p = tucker.plan(spec)
     coo_a = random_sparse_tensor(spec.shape, 0.05, seed=61)
@@ -169,10 +165,9 @@ def test_plan_zero_retrace_and_bit_parity_with_hooi_sparse(engine):
     assert res_a.retraces == 0 and res_b.retraces == 0
     assert res_a.dispatches == 1  # whole multi-sweep loop is one program
     for coo, res in ((coo_a, res_a), (coo_b, res_b)):
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            ref = hooi.hooi_sparse(coo, spec.ranks, n_iter=spec.n_iter,
-                                   method=spec.method, engine=engine)
+        tucker.clear_plan_cache()  # a fresh plan: new engine, new schedules
+        ref = tucker.decompose(coo, spec.ranks, n_iter=spec.n_iter,
+                               method=spec.method, engine=engine)
         np.testing.assert_array_equal(np.asarray(res.core), np.asarray(ref.core))
         for a, b in zip(res.factors, ref.factors):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -233,20 +228,24 @@ def test_batch_with_tol_matches_sequential():
 
 
 @pytest.mark.parametrize(
-    "engine,pipeline,use_kron_reuse",
-    [("pallas", "scan", False), ("xla", "scan", True), ("xla", "python", False)],
+    "engine,precision,use_kron_reuse",
+    [("pallas", "fp32", False), ("xla", "fp32", True),
+     ("xla", "bf16_fp32acc", False)],
 )
-def test_batch_fallback_configs_match_sequential(engine, pipeline, use_kron_reuse):
+def test_batch_fallback_configs_match_sequential(engine, precision, use_kron_reuse):
     """Configs whose schedules can't share one vmapped program fall back to
     sequential execution with identical results."""
     if engine not in ENGINES:
         pytest.skip("pallas unavailable")
     spec = _spec(shape=(10, 8, 6), ranks=(2, 2, 2), n_iter=2, engine=engine,
-                 pipeline=pipeline, use_kron_reuse=use_kron_reuse)
+                 precision=precision, use_kron_reuse=use_kron_reuse)
     p = tucker.plan(spec)
+    assert not p.supports_batched_dispatch
     coos = [random_sparse_tensor(spec.shape, 0.08, seed=s) for s in (85, 86)]
     seq = [p(c) for c in coos]
+    d0 = hooi.SWEEP_DISPATCH_COUNTS[(p.engine.name, "scan")]
     got = p.batch(coos)
+    assert hooi.SWEEP_DISPATCH_COUNTS[(p.engine.name, "scan")] - d0 == len(coos)
     for g, s in zip(got, seq):
         np.testing.assert_array_equal(np.asarray(g.core), np.asarray(s.core))
 
@@ -330,15 +329,13 @@ def test_batch_rejects_mixed_shapes_and_dense_specs():
 
 
 # ---------------------------------------------------------------------------
-# Satellite: use_kron_reuse follows ONE rule on both pipelines (regression
-# for the python-pipeline "reuse only when an engine happens to exist" bug).
+# Satellite: use_kron_reuse follows ONE rule, set by one engine helper.
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("pipeline", ["scan", "python"])
-def test_kron_reuse_actually_taken_on_both_pipelines(pipeline):
+def test_kron_reuse_actually_taken():
     spec = _spec(shape=(16, 14, 12), ranks=(3, 3, 2), engine="xla",
-                 pipeline=pipeline, use_kron_reuse=True)
+                 use_kron_reuse=True)
     p = tucker.plan(spec)
     assert p.engine.use_kron_reuse  # one helper, one rule
     coo = random_sparse_tensor(spec.shape, 0.06, seed=55)
@@ -347,18 +344,9 @@ def test_kron_reuse_actually_taken_on_both_pipelines(pipeline):
     assert sorted(p.engine.kron_plans) == [0, 1, 2]
     assert res.schedule_builds > 0
     # and it changed nothing numerically vs the non-reuse plan
-    plain = tucker.plan(_spec(shape=spec.shape, ranks=spec.ranks, engine="xla",
-                              pipeline=pipeline))(coo)
+    plain = tucker.plan(_spec(shape=spec.shape, ranks=spec.ranks,
+                              engine="xla"))(coo)
     np.testing.assert_allclose(res.fit_history, plain.fit_history, atol=1e-5)
-
-
-def test_kron_reuse_pipelines_agree():
-    spec_kw = dict(shape=(16, 14, 12), ranks=(3, 3, 2), engine="xla",
-                   use_kron_reuse=True)
-    coo = random_sparse_tensor((16, 14, 12), 0.06, seed=56)
-    a = tucker.plan(_spec(pipeline="python", **spec_kw))(coo)
-    b = tucker.plan(_spec(pipeline="scan", **spec_kw))(coo)
-    np.testing.assert_allclose(a.fit_history, b.fit_history, atol=1e-5)
 
 
 def test_prebuilt_engine_reuse_mismatch_warns_both_ways():
@@ -435,13 +423,13 @@ def test_result_metadata_fields():
 
 
 def test_plan_stats_accumulate():
-    spec = _spec(shape=(12, 10, 8), ranks=(2, 2, 2), pipeline="python")
-    p = tucker.plan(spec)
+    spec = _spec(shape=(12, 10, 8), ranks=(2, 2, 2))
+    p = tucker.TuckerPlan(spec)  # uncached: its stats are this test's alone
     coo = random_sparse_tensor(spec.shape, 0.05, seed=59)
     p(coo)
     p(coo)
     assert p.stats.calls == 2
-    assert p.stats.dispatches == 2 * spec.n_iter  # python driver: 1/sweep
+    assert p.stats.dispatches == 2  # the whole fit is one program: 1/call
 
 
 def test_dense_plan_warm_start():

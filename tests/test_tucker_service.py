@@ -351,18 +351,20 @@ def test_service_submit_validation():
 
 
 def test_service_nonbatchable_spec_warns_but_serves():
-    pyspec = tucker.TuckerSpec(shape=SPEC.shape, ranks=SPEC.ranks,
-                               method="gram", n_iter=2, pipeline="python")
+    # bf16 mixed precision has no vmapped program: the batch is fp32-only
+    bfspec = tucker.TuckerSpec(shape=SPEC.shape, ranks=SPEC.ranks,
+                               method="gram", n_iter=2, engine="xla",
+                               precision="bf16_fp32acc")
     coos = _coos(2, seed0=370)
     with TuckerService(ServiceConfig(max_batch=2, max_wait_ms=10_000.0)) as svc:
         with pytest.warns(RuntimeWarning, match="sequential"):
-            tickets = [svc.submit_coo(c, pyspec) for c in coos]
+            tickets = [svc.submit_coo(c, bfspec) for c in coos]
         results = [t.result(timeout=120) for t in tickets]
         snap = svc.metrics.snapshot()
-    # correct, but no amortization: one dispatch per sweep per member
-    assert snap["dispatches"] == 2 * pyspec.n_iter
+    # correct, but no amortization: one dispatch per member
+    assert snap["dispatches"] == len(coos)
     for c, r in zip(coos, results):
-        ref = tucker.plan(pyspec)(c)
+        ref = tucker.plan(bfspec)(c)
         np.testing.assert_array_equal(r.fit_history, ref.fit_history)
         # the fallback runs unpadded — metrics must say so, not the bucket
         assert r.timing.nnz_padded == c.nnz
@@ -575,6 +577,59 @@ def test_concurrent_submitters_share_plans_and_get_parity():
     ref = tucker.plan(spec)(coos[5])
     np.testing.assert_allclose(np.asarray(out[5].core), np.asarray(ref.core),
                                rtol=1e-5, atol=1e-5)
+
+
+def test_mixed_nnz_in_one_bucket_one_dispatch_per_flush():
+    """Requests of different nnz that share one bucket share each flush's
+    one dispatch: 8 requests at max_batch 4 are ceil(8 / 4) = 2 dispatches,
+    every member padded to the bucket, every result the sequential one."""
+    densities = (0.03, 0.04, 0.05)  # nnz 50, 67 and 84: all in bucket 128
+    coos = [random_sparse_tensor(SPEC.shape, densities[i % 3], seed=1100 + i)
+            for i in range(8)]
+    assert len({c.nnz for c in coos}) == 3
+    cfg = ServiceConfig(max_batch=4, max_wait_ms=60_000.0, bucket_base=128)
+    with TuckerService(cfg) as svc:
+        tickets = [svc.submit_coo(c, SPEC) for c in coos]
+        results = [t.result(timeout=120) for t in tickets]
+        snap = svc.metrics.snapshot()
+    assert snap["dispatches"] == 2 and snap["flushes"] == {"full": 2}
+    for c, r in zip(coos, results):
+        assert r.timing.batch_size == 4 and r.timing.nnz_padded == 128
+        ref = tucker.decompose(c, SPEC.ranks, method=SPEC.method,
+                               n_iter=SPEC.n_iter)
+        np.testing.assert_allclose(np.asarray(r.core), np.asarray(ref.core),
+                                   rtol=1e-4, atol=1e-4)
+        np.testing.assert_allclose(r.fit_history, ref.fit_history, atol=1e-5)
+
+
+def test_concurrent_flushes_bitwise_equal_to_sequential_flushes():
+    """A mixed-nnz load from two tenants, served once with one flush
+    executor and once with four: every result is bitwise the same and the
+    dispatch count is the same. Full-only flushes keep each key's batch
+    composition FIFO, so concurrency can change nothing but timing."""
+    spec_b = tucker.TuckerSpec(shape=SPEC.shape, ranks=(2, 3, 2),
+                               method="gram", n_iter=2)
+    densities = (0.03, 0.04, 0.05)
+    reqs = [(spec, random_sparse_tensor(SPEC.shape, densities[i % 3],
+                                        seed=1200 + 10 * t + i))
+            for i in range(4) for t, spec in enumerate((SPEC, spec_b))]
+
+    def serve(inflight):
+        cfg = ServiceConfig(max_batch=2, max_wait_ms=60_000.0, bucket_base=128,
+                            max_inflight_flushes=inflight)
+        with TuckerService(cfg) as svc:
+            tickets = [svc.submit_coo(c, spec) for spec, c in reqs]
+            results = [t.result(timeout=120) for t in tickets]
+            return results, svc.metrics.snapshot()["dispatches"]
+
+    seq, d_seq = serve(1)
+    conc, d_conc = serve(4)
+    assert d_seq == d_conc == 4  # 2 tenants x ceil(4 / 2) full flushes
+    for a, b in zip(seq, conc):
+        np.testing.assert_array_equal(np.asarray(a.core), np.asarray(b.core))
+        for fa, fb in zip(a.factors, b.factors):
+            np.testing.assert_array_equal(np.asarray(fa), np.asarray(fb))
+        np.testing.assert_array_equal(a.fit_history, b.fit_history)
 
 
 def test_service_plan_cache_capacity_and_eviction_hook():
