@@ -12,7 +12,9 @@ sweep across two interchangeable execution engines:
               (``kernels.kron_kernel``) on a host-side ``SortedCOO`` schedule
               (``sparse.layout``), core update on the blocked TTM kernel
               (``kernels.ttm_kernel``). Mosaic on TPU; interpret mode
-              elsewhere (slow but exact, which keeps CPU CI meaningful);
+              elsewhere (slow but exact, which keeps CPU CI meaningful).
+              Under ``shard`` each device streams its own nonzeros on a
+              per-shard schedule (``SweepEngine.shard_schedule``);
   ``auto``    ``pallas`` when a TPU is attached, ``xla`` otherwise.
 
 Engines are differentially tested against the dense ``ttm_chain`` oracle in
@@ -23,6 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import weakref
+from functools import partial
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -36,7 +39,9 @@ from repro.sparse.layout import (
     SortedCOO,
     build_kron_reuse,
     build_mode_layout,
+    build_shard_layouts,
     build_shard_schedule,
+    stack_shard_layouts,
 )
 
 ENGINES = ("xla", "pallas", "auto")
@@ -49,7 +54,7 @@ _SCHEDULE_BUILDS = {
         "host-side schedule constructions + device uploads",
         labels={"kind": kind},
     )
-    for kind in ("layout", "kron", "device", "shard")
+    for kind in ("layout", "kron", "device", "shard", "shard_layout")
 }
 
 
@@ -200,7 +205,8 @@ class SweepEngine:
         serving flushes) and ``device_put`` with a ``NamedSharding`` over
         ``nnz_axes`` — exactly once per (tensor, mesh, pad target): what the
         compiled shard_map pipeline (``core.hooi.build_sharded_program``)
-        consumes every sweep."""
+        consumes every sweep. On the Pallas engine it also carries every
+        shard's streaming schedule of every mode (``ShardSchedule.scheds``)."""
         self._bind(coo)
         bound_vals = self._shard_values() if self._shard_values is not None else None
         if bound_vals is not coo.values:
@@ -211,11 +217,35 @@ class SweepEngine:
             with _obs_span("engine.schedule.upload", kind="shard",
                            nnz=int(coo.nnz),
                            pad_nnz_to=pad_nnz_to and int(pad_nnz_to)):
-                self.shard_schedules[key] = build_shard_schedule(
+                sched = build_shard_schedule(
                     coo, mesh, tuple(nnz_axes), target_nnz=pad_nnz_to
                 )
             self._note_build("shard")
+            if self.name == "pallas":
+                sched = dataclasses.replace(
+                    sched, scheds=self._shard_mode_schedules(coo, sched)
+                )
+            self.shard_schedules[key] = sched
         return self.shard_schedules[key]
+
+    def _shard_mode_schedules(self, coo: SparseCOO, sched: ShardSchedule):
+        """Every shard's streaming schedule of every mode, from the shard's
+        contiguous slice of the padded nonzeros, padded to the mode's largest
+        block count and stacked on the mesh."""
+        layouts = build_shard_layouts(
+            coo.indices, tuple(coo.shape), sched.nnz_padded, sched.n_shards,
+            self.bn, self.bi,
+            span=partial(_obs_span, "engine.schedule.build", kind="shard_layout"),
+        )
+        for _ in layouts:
+            self._note_build("shard_layout")
+        return tuple(
+            stack_shard_layouts(
+                [layouts[(s, m)] for s in range(sched.n_shards)], m,
+                tuple(coo.shape), self.bn, self.bi, sched.mesh, sched.nnz_axes,
+            )
+            for m in range(coo.ndim)
+        )
 
     def apply_blocks(self, cfg) -> None:
         """Adopt an autotuned block configuration

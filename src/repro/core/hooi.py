@@ -4,7 +4,9 @@ Every sparse fit (paper Alg. 2) runs one compiled scan-over-sweeps program:
 ``_scan_sweeps`` on one device, ``_segment_scan_sweeps`` for the snapshot
 layer's resumable segments, ``_batched_scan_sweeps`` vmapped over a batch,
 and ``build_sharded_program`` over a device mesh. All four share the one
-sweep skeleton ``_sweep_scan``. The module also owns the trace/dispatch
+sweep skeleton ``_sweep_scan`` and take their per-mode unfoldings from
+``_engine_unfoldings``, so an engine runs the same code on one chip and on
+each chip of a mesh. The module also owns the trace/dispatch
 instrumentation the regression tests read. The front end lives in
 ``repro.tucker`` (plan/execute API).
 
@@ -466,35 +468,45 @@ def _batched_scan_sweeps(
 # Sharded scan pipeline: the multi-sweep loop as ONE shard_map-wrapped XLA
 # program over a device mesh. Nonzeros are sharded along the mesh's nnz axes
 # (see sparse.layout.ShardSchedule); inside the program each device runs the
-# Kron-accumulation over its local shard to get a *partial* Y_(n), a single
-# psum over the nnz axes completes the sum (the scatter-add is linear in the
-# nonzeros, so partial sums commute), and the small QRP factor update runs
-# replicated on every device. Per-sweep collective traffic is N psums of
-# I_n x prod_{t != n} R_t f32 — independent of nnz.
+# engine's Kron-accumulation over its local shard — on the Pallas engine the
+# fused Kron-scatter kernel on the shard's own schedule — to get a *partial*
+# Y_(n), a single psum over the nnz axes completes the sum (the scatter-add
+# is linear in the nonzeros, so partial sums commute), and the small QRP
+# factor update runs replicated on every device. Per-sweep collective
+# traffic is N psums of I_n x prod_{t != n} R_t f32 — independent of nnz.
 # ---------------------------------------------------------------------------
 
 def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
-                          resumable=False):
+                          resumable=False, engine_name="xla", interpret=False,
+                          precision="fp32", bl=None, bk=None):
     """Build the one-dispatch sharded sweep program (uncached: each call
     returns a fresh jit-wrapped callable with its own compile cache, so the
     CALLER owns the program's lifetime — ``TuckerPlan`` holds exactly one
     and the plan cache's LRU eviction frees the compiled executable with
     the plan, instead of pinning it in a module-level registry forever).
 
-    Returns ``program(indices, values, factors, xnorm2, tol)`` ->
+    Returns ``program(indices, values, factors, xnorm2, tol, scheds)`` ->
     ``(factors, core, hist)`` where indices/values are committed with a
     ``NamedSharding`` over ``nnz_axes`` (``sparse.layout.build_shard_schedule``)
-    and factors/xnorm2/tol are replicated. The whole multi-sweep loop —
-    cond-masked ``tol`` early exit included — is one XLA program; only the
-    fit history crosses back to host.
+    and factors/xnorm2/tol are replicated. ``scheds`` is ``None`` on the XLA
+    engine; on the Pallas engine it is the per-mode tuple of
+    ``DeviceSchedule`` objects whose fields stack every shard's own schedule on a
+    leading axis sharded over ``nnz_axes`` (``ShardSchedule.scheds``). Each
+    device builds its unfoldings exactly as the single-device program does
+    (``_engine_unfoldings``, so on Pallas it orders its nonzeros once a
+    call) and psums each ``Y_(n)``; the core update is the split path on the
+    psummed ``Y_(N)``. The whole multi-sweep loop — cond-masked ``tol``
+    early exit included — is one XLA program; only the fit history crosses
+    back to host.
 
     ``resumable=True`` builds the snapshot-segment variant instead:
     ``program(indices, values, factors, core, xnorm2, tol, prev_err, done,
-    n_done, total_sweeps)`` -> ``(factors, core, hist, (prev_err, done,
-    n_done))`` — ``n_iter`` sweeps continuing from an explicit replicated
-    carry, with the job's true budget dynamic so one compiled program serves
-    every segment at any resume offset. Factors are not donated there: the
-    host spills the carry to a checkpoint right after each dispatch.
+    n_done, total_sweeps, scheds)`` -> ``(factors, core, hist, (prev_err,
+    done, n_done))`` — ``n_iter`` sweeps continuing from an explicit
+    replicated carry, with the job's true budget dynamic so one compiled
+    program serves every segment at any resume offset. Factors are not
+    donated there: the host spills the carry to a checkpoint right after
+    each dispatch.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -503,29 +515,35 @@ def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
     n = len(shape)
     n_shards = int(np.prod([mesh.shape[a] for a in nnz_axes]))
 
-    def _unfoldings(indices, values):
+    def _unfoldings(indices, values, scheds):
         # per-device view: indices (nnz_padded / n_shards, N), values
-        # (nnz_padded / n_shards,), factors replicated.
-        def mode_unfolding(fs, mode):
-            partial_y = sparse_ttm_chain(
-                SparseCOO(indices, values, shape), fs, mode
-            )
+        # (nnz_padded / n_shards,), each schedule field (1, ...) — this
+        # shard's own — and factors replicated.
+        if scheds is not None:
+            scheds = tuple(jax.tree.map(lambda a: a[0], s) for s in scheds)
+        mode_unfolding, core_unfolding = _engine_unfoldings(
+            indices, values, scheds, shape=shape, engine_name=engine_name,
+            interpret=interpret, use_reuse=False, precision=precision,
+            bl=bl, bk=bk,
+        )
+
+        def summed_unfolding(fs, mode):
+            partial_y = mode_unfolding(fs, mode)
             with jax.named_scope(stages.PSUM):
                 return jax.lax.psum(partial_y, nnz_axes)
 
-        def core_unfolding(fs, y_n):
-            with jax.named_scope(stages.CORE):
-                return ttm_unfolded(y_n.T, fs[-1].T).T
-
-        return mode_unfolding, core_unfolding
+        return summed_unfolding, core_unfolding
 
     factor_specs = tuple(P(None, None) for _ in range(n))
     core_spec = P(*([None] * n))
+    # every leaf of every mode's stacked schedule: this shard's row
+    sched_spec = P(nnz_axes)
+    key = ("sharded", engine_name, shape, ranks, method, int(n_iter), n_shards)
 
     if resumable:
         def segment_body(indices, values, factors, core, xnorm2, tol,
-                         prev_err, done, n_done, total_sweeps):
-            mode_unfolding, core_unfolding = _unfoldings(indices, values)
+                         prev_err, done, n_done, total_sweeps, scheds):
+            mode_unfolding, core_unfolding = _unfoldings(indices, values, scheds)
             return _sweep_scan(
                 mode_unfolding, core_unfolding, factors, xnorm2, tol,
                 ranks=ranks, method=method, n_iter=n_iter,
@@ -541,6 +559,7 @@ def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
             core_spec,  # core carry (replicated)
             P(), P(),  # xnorm2, tol
             P(), P(), P(), P(),  # prev_err, done, n_done, total_sweeps
+            sched_spec,  # per-shard schedules
         )
         out_specs = (
             factor_specs,
@@ -554,18 +573,17 @@ def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
         )
 
         def traced(indices, values, factors, core, xnorm2, tol,
-                   prev_err, done, n_done, total_sweeps):
+                   prev_err, done, n_done, total_sweeps, scheds):
             # trace-time only (outside the shard_map body, which jax may
             # trace more than once per build): cache hits never reach here.
-            SWEEP_TRACE_COUNTS.tick(("sharded", shape, ranks, method, "segment", int(n_iter),
-                 n_shards))
+            SWEEP_TRACE_COUNTS.tick(key + ("segment",))
             return inner(indices, values, factors, core, xnorm2, tol,
-                         prev_err, done, n_done, total_sweeps)
+                         prev_err, done, n_done, total_sweeps, scheds)
 
         return jax.jit(traced)
 
-    def sweep_body(indices, values, factors, xnorm2, tol):
-        mode_unfolding, core_unfolding = _unfoldings(indices, values)
+    def sweep_body(indices, values, factors, xnorm2, tol, scheds):
+        mode_unfolding, core_unfolding = _unfoldings(indices, values, scheds)
         fs, core, hist, _ = _sweep_scan(
             mode_unfolding, core_unfolding, factors, xnorm2, tol,
             ranks=ranks, method=method, n_iter=n_iter,
@@ -579,6 +597,7 @@ def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
         factor_specs,  # factors (replicated)
         P(),  # xnorm2
         P(),  # tol
+        sched_spec,  # per-shard schedules
     )
     out_specs = (
         factor_specs,  # factors
@@ -590,11 +609,11 @@ def build_sharded_program(mesh, nnz_axes, *, shape, ranks, method, n_iter,
         check_vma=False,
     )
 
-    def traced(indices, values, factors, xnorm2, tol):
+    def traced(indices, values, factors, xnorm2, tol, scheds):
         # trace-time only (outside the shard_map body, which jax may trace
         # more than once per build): cache hits never reach this line.
-        SWEEP_TRACE_COUNTS.tick(("sharded", shape, ranks, method, int(n_iter), n_shards))
-        return inner(indices, values, factors, xnorm2, tol)
+        SWEEP_TRACE_COUNTS.tick(key)
+        return inner(indices, values, factors, xnorm2, tol, scheds)
 
     # factors are donated like the single-device _scan_sweeps: the plan
     # hands in freshly-initialized (or defensively copied) buffers, so the

@@ -22,7 +22,10 @@ with scalar prefetch.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import TYPE_CHECKING, NamedTuple, Optional, Tuple
 
 import jax
@@ -368,6 +371,9 @@ class ShardSchedule:
     n_shards: int
     nnz: int  # real stored nonzeros (pre-padding)
     nnz_padded: int
+    # per mode, every shard's streaming schedule stacked on a leading shard
+    # axis (:func:`stack_shard_layouts`); None on the XLA engine.
+    scheds: Optional[Tuple[DeviceSchedule, ...]] = None
 
     @property
     def shard_counts(self) -> np.ndarray:
@@ -428,6 +434,142 @@ def build_shard_schedule(
         n_shards=n_shards,
         nnz=nnz,
         nnz_padded=int(idx.shape[0]),
+    )
+
+
+# ---------------------------------------------------------------------------
+# Per-shard streaming schedules: each chip of the sharded program streams its
+# own contiguous slice of the padded nonzeros through the Kron kernels, so
+# each shard gets a schedule of its own, with indices local to its slice. The
+# shards' schedules are stacked into one array per field, so every shard's
+# must have the same block count, and every shard visits every row block, so
+# no shard's kernel leaves a row of its partial Y_(n) unwritten.
+# ---------------------------------------------------------------------------
+
+
+def shard_rows(indices: np.ndarray, nnz_padded: int, n_shards: int,
+               shard: int, mode: int) -> np.ndarray:
+    """Mode-``mode`` coordinates of shard ``shard``'s slice of the nonzeros
+    padded to ``nnz_padded`` (padding slots read coordinate 0, as
+    ``SparseCOO.pad_to`` pads)."""
+    per = int(nnz_padded) // int(n_shards)
+    part = np.asarray(indices)[shard * per:(shard + 1) * per, mode]
+    rows = np.zeros((per,), dtype=np.int64)
+    rows[:part.shape[0]] = part
+    return rows
+
+
+def shard_block_count(rows: np.ndarray, n_rows: int, bn: int, bi: int) -> int:
+    """nnz blocks of one shard's schedule: each row block's nonzeros in
+    ``bn``-slot blocks, and one all-padding block for a row block the shard
+    never visits."""
+    n_row_blocks = max(1, -(-int(n_rows) // bi))
+    cnt = np.bincount(np.asarray(rows) // bi, minlength=n_row_blocks)
+    return int(np.maximum(-(-cnt // bn), 1).sum())
+
+
+class ShardLayout(NamedTuple):
+    """One shard's mode schedule, padded to the shards' common block count:
+    the :class:`SortedCOO` fields the kernels read, indices local to the
+    shard's slice."""
+
+    order: np.ndarray  # (n_blocks * bn,)
+    valid: np.ndarray
+    rel_row: np.ndarray
+    blkmap: np.ndarray  # (n_blocks,)
+    first: np.ndarray
+    last: np.ndarray
+    n_row_blocks: int
+    pad_blocks: int  # all-padding blocks appended to reach n_blocks
+
+
+def build_shard_layout(rows: np.ndarray, n_rows: int, bn: int, bi: int,
+                       n_blocks: int) -> ShardLayout:
+    """:func:`build_schedule` over one shard's rows, then padded to
+    ``n_blocks`` (at least :func:`shard_block_count`).
+
+    A row block the shard never visits gets one phantom coordinate, so the
+    schedule gives it one block whose slots are all padding: the kernel
+    zeroes that block of the shard's partial Y_(n) and no row mask is
+    needed. The blocks appended to reach ``n_blocks`` are all padding and
+    continue the last row-block group: the same target, ``first`` 0, and
+    ``last`` moved to the new final block."""
+    rows = np.asarray(rows).astype(np.int64)
+    nnz = rows.shape[0]
+    n_row_blocks = max(1, -(-int(n_rows) // bi))
+    visited = np.zeros((n_row_blocks,), dtype=bool)
+    visited[rows // bi] = True
+    phantom = np.flatnonzero(~visited) * bi
+    order, valid, rel, blkmap, first, last, _, _ = build_schedule(
+        np.concatenate([rows, phantom]), n_rows, bn, bi
+    )
+    fake = order >= nnz
+    order[fake], valid[fake], rel[fake] = 0, 0.0, 0
+    pad_blocks = int(n_blocks) - blkmap.shape[0]
+    order, valid, rel = (np.pad(a, (0, pad_blocks * bn)) for a in (order, valid, rel))
+    blkmap = np.pad(blkmap, (0, pad_blocks), mode="edge")
+    first, last = np.pad(first, (0, pad_blocks)), np.pad(last, (0, pad_blocks))
+    last[-pad_blocks - 1], last[-1] = 0, 1
+    return ShardLayout(order, valid, rel, blkmap, first, last, n_row_blocks,
+                       pad_blocks)
+
+
+def build_shard_layouts(indices, shape: Tuple[int, ...], nnz_padded: int,
+                        n_shards: int, bn: int, bi: int, span=None):
+    """Every shard's layout of every mode (:func:`build_shard_layout`), each
+    mode's padded to the largest block count among its shards: a dict
+    ``(shard, mode) -> ShardLayout``. ``indices`` are the tensor's
+    coordinates before the shard padding.
+
+    The shards x modes builds run on a thread pool: numpy's sort, which
+    dominates them, releases the GIL. ``span``, when given, is entered
+    around each build as ``span(shard=, mode=, n_blocks=, pad_blocks=)``,
+    ``n_blocks`` the shard's own block count and ``pad_blocks`` the blocks
+    appended to it."""
+    idx = np.asarray(indices)
+    ndim = len(shape)
+    tasks = [(s, m) for m in range(ndim) for s in range(n_shards)]
+    span = span or (lambda **_: contextlib.nullcontext())
+
+    def rows(task):
+        return shard_rows(idx, nnz_padded, n_shards, *task)
+
+    def count(task):
+        return shard_block_count(rows(task), shape[task[1]], bn, bi)
+
+    def build(task):
+        s, m = task
+        own = counts[task]
+        with span(shard=s, mode=m, n_blocks=own, pad_blocks=most[m] - own):
+            return build_shard_layout(rows(task), shape[m], bn, bi, most[m])
+
+    with ThreadPoolExecutor(min(len(tasks), os.cpu_count() or 1)) as pool:
+        counts = dict(zip(tasks, pool.map(count, tasks)))
+        most = {m: max(counts[(s, m)] for s in range(n_shards))
+                for m in range(ndim)}
+        return dict(zip(tasks, pool.map(build, tasks)))
+
+
+def stack_shard_layouts(layouts, mode: int, shape: Tuple[int, ...], bn: int,
+                        bi: int, mesh, nnz_axes: Tuple[str, ...]) -> DeviceSchedule:
+    """Stack the shards' layouts of one mode on a leading shard axis and
+    ``device_put`` each field once, sharded over ``nnz_axes``: each device
+    holds its own shard's schedule. The block geometry is static and the
+    same for every shard."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    sharding = NamedSharding(mesh, P(tuple(nnz_axes)))
+
+    def put(field):
+        return jax.device_put(np.stack([getattr(s, field) for s in layouts]),
+                              sharding)
+
+    return DeviceSchedule(
+        order=put("order"), valid=put("valid"), rel_row=put("rel_row"),
+        blkmap=put("blkmap"), first=put("first"), last=put("last"),
+        row_mask=None, kron_unique=None, kron_inverse=None,
+        mode=mode, shape=tuple(shape), n_row_blocks=layouts[0].n_row_blocks,
+        bn=bn, bi=bi, kron_modes=None,
     )
 
 

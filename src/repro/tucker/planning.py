@@ -247,11 +247,6 @@ class TuckerPlan:
         _mesh: Any = None,
     ) -> None:
         self.spec = spec
-        if spec.shard is not None:
-            # the sharded pipeline is plain XLA inside shard_map: force the
-            # resolution (spec validation already rejected engine='pallas';
-            # 'auto' must not pick pallas on a TPU host either).
-            _resolved = "xla"
         self.mesh = (
             _mesh if _mesh is not None
             else (mesh_for_shard(spec.shard) if spec.shard is not None else None)
@@ -280,11 +275,6 @@ class TuckerPlan:
             self.engine: Optional[SweepEngine] = engine_for_spec(
                 spec, prebuilt=engine, resolved=_resolved
             )
-            if spec.shard is not None and self.engine.name != "xla":
-                raise ValueError(
-                    f"a sharded plan requires the XLA engine, but the "
-                    f"prebuilt SweepEngine is {self.engine.name!r}"
-                )
         else:
             if engine is not None:
                 raise ValueError(
@@ -580,30 +570,22 @@ class TuckerPlan:
                 sched = eng.shard_schedule(coo, self.mesh, self._nnz_axes)
                 if spec.snapshot is not None:
                     seg = spec.snapshot.segment_len
-                    prog = _hooi.build_sharded_program(
-                        self.mesh, self._nnz_axes,
-                        shape=spec.shape, ranks=spec.ranks,
-                        method=spec.method, n_iter=seg, resumable=True,
-                    )
+                    prog = self._sharded(seg, resumable=True)
                     core = jnp.zeros(tuple(spec.ranks), dtype=work_dtype)
                     lowered = prog.lower(
                         sched.indices, sched.values, tuple(factors), core,
                         xnorm2, tol, jnp.float32(jnp.inf),
                         jnp.asarray(False), jnp.int32(0),
-                        jnp.int32(spec.n_iter),
+                        jnp.int32(spec.n_iter), sched.scheds,
                     )
                     # factors NOT donated: the host spills the carry to a
                     # checkpoint right after each segment dispatch.
                     kind, n_sweeps, donated = "sharded-segment", seg, ()
                 else:
-                    prog = _hooi.build_sharded_program(
-                        self.mesh, self._nnz_axes,
-                        shape=spec.shape, ranks=spec.ranks,
-                        method=spec.method, n_iter=spec.n_iter,
-                    )
+                    prog = self._sharded(spec.n_iter)
                     lowered = prog.lower(
                         sched.indices, sched.values, tuple(factors),
-                        xnorm2, tol,
+                        xnorm2, tol, sched.scheds,
                     )
                     kind, n_sweeps = "sharded", spec.n_iter
                     # donate_argnums=(2,): the factors tuple flattens to
@@ -901,10 +883,8 @@ class TuckerPlan:
                 dtype=jnp.promote_types(coo.values.dtype, jnp.float32),
             )
             if self._sharded_segment_program is None:  # once per plan
-                self._sharded_segment_program = _hooi.build_sharded_program(
-                    self.mesh, self._nnz_axes,
-                    shape=spec.shape, ranks=spec.ranks, method=spec.method,
-                    n_iter=segment_len, resumable=True,
+                self._sharded_segment_program = self._sharded(
+                    segment_len, resumable=True
                 )
             # the segment outputs come back replicated over the mesh; commit
             # the first segment's carry the same way, so every segment (the
@@ -920,6 +900,7 @@ class TuckerPlan:
                 out = self._sharded_segment_program(
                     sched.indices, sched.values, tuple(factors), core,
                     xnorm2, tol, prev_err_d, done_d, n_done_d, total_sweeps,
+                    sched.scheds,
                 )
                 _hooi.SWEEP_DISPATCH_COUNTS.tick(("sharded", "scan"))
                 return out
@@ -1038,6 +1019,17 @@ class TuckerPlan:
             res.shard_imbalance = sched.imbalance
         return res
 
+    def _sharded(self, n_iter: int, resumable: bool = False) -> Any:
+        """A fresh sharded sweep program on this plan's mesh and engine."""
+        spec, eng = self.spec, self.engine
+        return _hooi.build_sharded_program(
+            self.mesh, self._nnz_axes,
+            shape=spec.shape, ranks=spec.ranks, method=spec.method,
+            n_iter=n_iter, resumable=resumable, engine_name=eng.name,
+            interpret=eng.resolved_interpret() if eng.name == "pallas" else False,
+            precision=eng.precision, bl=eng.bl, bk=eng.bk,
+        )
+
     def _run_sparse_sharded(self, coo: Any, factors: Any, xnorm2: Any,
                             pad_nnz_to: Optional[int] = None) -> TuckerResult:
         """One shard_map-wrapped scan dispatch over the plan's mesh: nonzeros
@@ -1051,11 +1043,7 @@ class TuckerPlan:
             coo, self.mesh, self._nnz_axes, pad_nnz_to=pad_nnz_to
         )
         if self._sharded_program is None:  # once per plan (under _exec_lock)
-            self._sharded_program = _hooi.build_sharded_program(
-                self.mesh, self._nnz_axes,
-                shape=spec.shape, ranks=spec.ranks, method=spec.method,
-                n_iter=spec.n_iter,
-            )
+            self._sharded_program = self._sharded(spec.n_iter)
         traces0 = _total_traces()
         coll_bytes = psum_bytes_per_sweep(
             spec.shape, spec.ranks,
@@ -1066,7 +1054,7 @@ class TuckerPlan:
                        collective_bytes_per_sweep=int(coll_bytes)) as dsp:
             fs, core, hist_dev = self._sharded_program(
                 sched.indices, sched.values, tuple(factors), xnorm2,
-                jnp.float32(spec.tol),
+                jnp.float32(spec.tol), sched.scheds,
             )
             _hooi.SWEEP_DISPATCH_COUNTS.tick(("sharded", "scan"))
             hist = np.asarray(_hooi._fetch_history(hist_dev))  # the one d2h transfer
@@ -1414,20 +1402,19 @@ def plan(spec: TuckerSpec, *, engine: Optional[SweepEngine] = None,
         raise ValueError("mesh= only applies to specs with a ShardSpec")
     if spec.algorithm != "sparse":
         key = (spec, "xla")
-    elif spec.shard is not None:
-        # the key carries the mesh fingerprint: identical mesh -> cache hit
-        # (one compiled shard_map program per mesh), changed device set ->
-        # a fresh plan, never the wrong mesh's executable.
-        mesh = mesh if mesh is not None else mesh_for_shard(spec.shard)
-        key = (spec, "xla", mesh_fingerprint(mesh))
-        return _PLAN_CACHE.get_or_create(
-            key, lambda: TuckerPlan(spec, _resolved="xla", _mesh=mesh)
-        )
     else:
         # resolve on every lookup: 'auto'/'pallas' may map differently (and
         # warn) as backend availability changes.
         key = (spec, resolve_engine(spec.engine))
-    return _PLAN_CACHE.get_or_create(key, lambda: TuckerPlan(spec, _resolved=key[1]))
+    if spec.shard is not None:
+        # the key carries the mesh fingerprint: identical mesh -> cache hit
+        # (one compiled shard_map program per mesh), changed device set ->
+        # a fresh plan, never the wrong mesh's executable.
+        mesh = mesh if mesh is not None else mesh_for_shard(spec.shard)
+        key += (mesh_fingerprint(mesh),)
+    return _PLAN_CACHE.get_or_create(
+        key, lambda: TuckerPlan(spec, _resolved=key[1], _mesh=mesh)
+    )
 
 
 def clear_plan_cache() -> None:

@@ -211,9 +211,11 @@ class TuckerSpec:
       shard: a :class:`ShardSpec` to run the compiled sweep pipeline
         data-parallel over a device mesh (nonzeros sharded, factors
         replicated, one psum per mode per sweep), or ``None`` for
-        single-device execution. Requires the sparse algorithm with the
-        plain XLA engine (no Kron-reuse — its dedup plan is a per-tensor
-        host artifact that cannot shard).
+        single-device execution. Each device runs the spec's engine on its
+        own nonzeros (on Pallas, the fused Kron-scatter kernel on a
+        per-shard schedule). Requires the sparse algorithm, fp32 and no
+        Kron-reuse (its dedup plan is a per-tensor host artifact that
+        cannot shard).
       snapshot: a :class:`SnapshotSpec` to run the compiled sweep pipeline
         in chunked segments with the carry checkpointed at each interval
         (resumable via ``tucker.resume``), or ``None`` for the one-dispatch
@@ -283,11 +285,6 @@ class TuckerSpec:
                 raise ValueError(
                     f"shard requires algorithm='sparse' (only COO nonzeros "
                     f"have an nnz axis to shard), got {self.algorithm!r}"
-                )
-            if self.engine == "pallas":
-                raise ValueError(
-                    "shard requires the XLA engine: the Pallas kernels do "
-                    "not run inside shard_map (use engine='xla' or 'auto')"
                 )
             if self.use_kron_reuse:
                 raise ValueError(

@@ -6,6 +6,8 @@ permutation/padding the schedule applies, ``to_dense()`` — and therefore
 every contraction — is unchanged. And the sparse TTM chain must equal the
 dense ``ttm_chain`` oracle on arbitrary COO tensors, duplicates included.
 """
+import contextlib
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -146,6 +148,59 @@ def test_shard_padding_preserves_unfolding_product(coo, data, n_shards, seed):
     got = np.asarray(sparse_ttm_chain(padded, factors, mode))
     want = np.asarray(sparse_ttm_chain(coo, factors, mode))
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+@SETTINGS
+@given(coo=coo_tensors(max_nnz=40), n_shards=st.integers(1, 5),
+       extra=st.integers(0, 9), bn=st.sampled_from([2, 4, 8]),
+       bi=st.sampled_from([1, 2, 4]))
+def test_shard_layouts_stream_each_nonzero_once(coo, n_shards, extra, bn, bi):
+    """Per-shard schedules of the sharded Pallas program: every shard of a
+    mode has the same block count; each slot of the padded nonzeros is
+    streamed exactly once per mode across the shards, at its own row;
+    padding slots carry ``valid == 0``; ``first``/``last`` mark the
+    ``blkmap`` group boundaries, one group per row block in every shard;
+    and the span of each build reports the blocks appended to it."""
+    from repro.sparse.layout import build_shard_layouts
+
+    padded = shard_pad_nnz(coo.nnz + extra, n_shards)
+    per = padded // n_shards
+    idx = np.zeros((padded, coo.ndim), dtype=np.int64)
+    idx[:coo.nnz] = np.asarray(coo.indices)
+    spans = {}
+
+    @contextlib.contextmanager
+    def span(**attrs):
+        spans[(attrs["shard"], attrs["mode"])] = attrs
+        yield
+
+    layouts = build_shard_layouts(coo.indices, coo.shape, padded, n_shards,
+                                  bn, bi, span=span)
+    assert sorted(layouts) == sorted(spans)
+    for mode in range(coo.ndim):
+        n_row_blocks = -(-coo.shape[mode] // bi)
+        mine = [layouts[(s, mode)] for s in range(n_shards)]
+        assert len({lay.blkmap.shape[0] for lay in mine}) == 1
+        streamed = []
+        for s, lay in enumerate(mine):
+            n_blocks = lay.blkmap.shape[0]
+            assert lay.order.shape == lay.valid.shape == (n_blocks * bn,)
+            real = lay.valid > 0
+            assert not lay.order[~real].any() and not lay.rel_row[~real].any()
+            rows = idx[s * per + lay.order, mode]
+            block_row = np.repeat(lay.blkmap, bn) * bi + lay.rel_row
+            np.testing.assert_array_equal(block_row[real], rows[real])
+            streamed.append(s * per + lay.order[real])
+            starts = np.r_[True, lay.blkmap[1:] != lay.blkmap[:-1]]
+            ends = np.r_[lay.blkmap[1:] != lay.blkmap[:-1], True]
+            np.testing.assert_array_equal(lay.first, starts)
+            np.testing.assert_array_equal(lay.last, ends)
+            assert sorted(lay.blkmap[starts]) == list(range(n_row_blocks))
+            attrs = spans[(s, mode)]
+            assert attrs["pad_blocks"] == lay.pad_blocks
+            assert attrs["n_blocks"] + attrs["pad_blocks"] == n_blocks
+        np.testing.assert_array_equal(np.sort(np.concatenate(streamed)),
+                                      np.arange(padded))
 
 
 # ---------------------------------------------------------------------------

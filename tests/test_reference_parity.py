@@ -13,8 +13,9 @@ and ``reference.init_factors`` draw the same start) and holds the program to:
 
 The variants are the single-device scan program on both engines, the XLA
 engine with Kron reuse, the snapshot segment program, the vmapped batch
-program and the sharded program (four forced host devices, in one
-subprocess), each at tensor orders 2, 3 and 4; then the ``gram`` factor
+program and the sharded program on both engines (four forced host devices,
+in one subprocess; order 4 takes the chained ``kron_contrib`` +
+``scatter_rows_pallas`` path on Pallas), each at tensor orders 2, 3 and 4; then the ``gram`` factor
 update and ``bf16_fp32acc`` on both engines at order 3. On these tensors the
 fp32 programs agree with the reference to about 1e-7 in both numbers.
 """
@@ -49,7 +50,8 @@ ORDERS = {
     3: ((24, 20, 16), (4, 3, 3)),
     4: ((10, 9, 8, 7), (3, 3, 2, 2)),
 }
-VARIANTS = ("scan-xla", "scan-pallas", "kron-reuse", "segments", "batch", "sharded")
+VARIANTS = ("scan-xla", "scan-pallas", "kron-reuse", "segments", "batch", "sharded",
+            "sharded-pallas")
 
 
 def _tensor(order, seed):
@@ -91,21 +93,24 @@ _SHARDED_SCRIPT = """
     for order, (shape, ranks) in %(orders)r.items():
         coo, _ = low_rank_sparse_tensor(shape, ranks, 0.1, seed=order,
                                         noise=0.05)
-        spec = tucker.TuckerSpec(shape=shape, ranks=ranks, n_iter=%(n_iter)d,
-                                 method="householder",
-                                 shard=tucker.ShardSpec(num_devices=4))
-        res = tucker.plan(spec)(coo, key=jax.random.PRNGKey(100 + order))
-        assert res.dispatches == 1
-        np.savez(%(out)r + "/order%%d.npz" %% order, core=np.asarray(res.core),
-                 hist=res.fit_history,
-                 **{"f%%d" %% i: np.asarray(f) for i, f in enumerate(res.factors)})
+        for variant, engine in (("sharded", "auto"), ("sharded-pallas", "pallas")):
+            spec = tucker.TuckerSpec(shape=shape, ranks=ranks, n_iter=%(n_iter)d,
+                                     method="householder", engine=engine,
+                                     shard=tucker.ShardSpec(num_devices=4))
+            res = tucker.plan(spec)(coo, key=jax.random.PRNGKey(100 + order))
+            assert res.dispatches == 1
+            assert res.engine == ("xla" if engine == "auto" else engine)
+            np.savez(%(out)r + "/%%s-order%%d.npz" %% (variant, order),
+                     core=np.asarray(res.core), hist=res.fit_history,
+                     **{"f%%d" %% i: np.asarray(f) for i, f in enumerate(res.factors)})
 """
 
 
 @pytest.fixture(scope="module")
 def sharded(tmp_path_factory):
-    """The sharded program at every order, run once in one subprocess over
-    four forced host devices (this process keeps its one device)."""
+    """The sharded program at every order, on the XLA engine (``auto`` on a
+    CPU) and on Pallas, run once in one subprocess over four forced host
+    devices (this process keeps its one device)."""
     out = str(tmp_path_factory.mktemp("sharded"))
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -131,9 +136,9 @@ class _Saved:
 def test_program_matches_reference(variant, order, tmp_path, request):
     ranks = ORDERS[order][1]
     coo, key = _tensor(order, order), _key(order)
-    if variant == "sharded":
+    if variant.startswith("sharded"):
         d = request.getfixturevalue("sharded")
-        res = _Saved(os.path.join(d, f"order{order}.npz"))
+        res = _Saved(os.path.join(d, f"{variant}-order{order}.npz"))
     elif variant == "batch":
         p = tucker.plan(_spec(order))
         assert p.supports_batched_dispatch  # the vmapped program, not a loop

@@ -25,54 +25,121 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 
 DEVICE_COUNTS = (1, 2, 4)
 METHODS = ("svd", "gram")
+# "xla" is the spec's default engine="auto" on a CPU; "pallas" runs the
+# fused Kron-scatter kernel in interpret mode on each device
+ENGINES = ("xla", "pallas")
 # ragged on purpose: 397 is odd and divides by neither 2 nor 4, so every
 # multi-device case exercises the shard padding path.
 RAGGED_NNZ = 397
 
 _MATRIX_SCRIPT = """
-    import json, numpy as np, jax
-    from repro import tucker
+    import json, tempfile, numpy as np, jax
+    from repro import obs, tucker
     from repro.core import hooi
     from repro.core.coo import SparseCOO
     from repro.sparse.generators import random_sparse_tensor
 
     SHAPE, RANKS, N_ITER = (18, 15, 12), (3, 2, 2), 3
     DEVICE_COUNTS, METHODS, RAGGED_NNZ = %(devices)r, %(methods)r, %(nnz)d
+    ENGINES = %(engines)r
 
     full = random_sparse_tensor(SHAPE, 0.25, seed=11)
     assert full.nnz >= RAGGED_NNZ
     coo = SparseCOO(full.indices[:RAGGED_NNZ], full.values[:RAGGED_NNZ], SHAPE)
 
+    def maxdiffs(res, ref):
+        return {
+            "fit_maxdiff": float(np.abs(res.fit_history - ref.fit_history).max()),
+            "core_maxdiff": float(np.abs(np.asarray(res.core)
+                                         - np.asarray(ref.core)).max()),
+            "factor_maxdiff": float(max(
+                np.abs(np.asarray(a) - np.asarray(b)).max()
+                for a, b in zip(res.factors, ref.factors))),
+        }
+
     out = {"n_devices": len(jax.devices()), "cases": []}
     refs = {}
-    for method in METHODS:
-        spec = tucker.TuckerSpec(shape=SHAPE, ranks=RANKS, method=method,
-                                 engine="xla", n_iter=N_ITER)
-        refs[method] = tucker.plan(spec)(coo)
-
-    for d in DEVICE_COUNTS:
+    for engine in ENGINES:
         for method in METHODS:
-            spec = tucker.TuckerSpec(
-                shape=SHAPE, ranks=RANKS, method=method, n_iter=N_ITER,
-                shard=tucker.ShardSpec(num_devices=d))
-            plan = tucker.plan(spec)
-            res = plan(coo)
-            ref = refs[method]
-            out["cases"].append({
-                "devices": d, "method": method,
-                "fit_maxdiff": float(np.abs(res.fit_history - ref.fit_history).max()),
-                "core_maxdiff": float(np.abs(np.asarray(res.core)
-                                             - np.asarray(ref.core)).max()),
-                "factor_maxdiff": float(max(
-                    np.abs(np.asarray(a) - np.asarray(b)).max()
-                    for a, b in zip(res.factors, ref.factors))),
-                "n_sweeps": res.n_sweeps,
-                "dispatches": res.dispatches,
-                "retraces": res.retraces,
-                "collective_bytes_per_sweep": res.collective_bytes_per_sweep,
-                "shard_imbalance": res.shard_imbalance,
-                "cache_hit_on_replan": tucker.plan(spec) is plan,
-            })
+            spec = tucker.TuckerSpec(shape=SHAPE, ranks=RANKS, method=method,
+                                     engine=engine, n_iter=N_ITER)
+            refs[engine, method] = tucker.plan(spec)(coo)
+
+    obs.configure(enabled=True)
+    layout_builds = obs.registry.counter("repro_schedule_builds_total",
+                                         labels={"kind": "shard_layout"})
+    for engine in ENGINES:
+        for d in DEVICE_COUNTS:
+            for method in METHODS:
+                spec = tucker.TuckerSpec(
+                    shape=SHAPE, ranks=RANKS, method=method, n_iter=N_ITER,
+                    engine="auto" if engine == "xla" else engine,
+                    shard=tucker.ShardSpec(num_devices=d))
+                plan = tucker.plan(spec)
+                obs.tracer.clear()
+                builds0 = layout_builds.value
+                res = plan(coo)
+                spans = [e.attrs for e in obs.tracer.events()
+                         if e.attrs.get("kind") == "shard_layout"]
+                sched = next(iter(plan.engine.shard_schedules.values()))
+                out["cases"].append({
+                    "engine": res.engine, "devices": d, "method": method,
+                    **maxdiffs(res, refs["xla", method]),
+                    "vs_single_engine": maxdiffs(res, refs[engine, method]),
+                    "n_sweeps": res.n_sweeps,
+                    "dispatches": res.dispatches,
+                    "retraces": res.retraces,
+                    "collective_bytes_per_sweep": res.collective_bytes_per_sweep,
+                    "shard_imbalance": res.shard_imbalance,
+                    "cache_hit_on_replan": tucker.plan(spec) is plan,
+                    "layout_builds": layout_builds.value - builds0,
+                    "layout_spans": [[a["shard"], a["mode"], a["n_blocks"],
+                                      a["pad_blocks"]] for a in spans],
+                    "stacked_blocks": (
+                        None if sched.scheds is None
+                        else [list(sc.blkmap.shape) for sc in sched.scheds]),
+                })
+    obs.configure(enabled=False)
+
+    # -- Pallas on a skewed tensor: the nonzeros sorted by their mode-0 row,
+    # so each contiguous shard covers a band of rows and visits only some of
+    # mode 0's 128-row blocks -------------------------------------------------
+    skew_shape = (300, 20, 16)
+    sk = random_sparse_tensor(skew_shape, 0.02, seed=5)
+    order = np.argsort(np.asarray(sk.indices)[:, 0], kind="stable")
+    sk = SparseCOO(sk.indices[order], sk.values[order], skew_shape)
+    ref = tucker.plan(tucker.TuckerSpec(shape=skew_shape, ranks=RANKS,
+                                        engine="xla", n_iter=N_ITER))(sk)
+    plan = tucker.plan(tucker.TuckerSpec(
+        shape=skew_shape, ranks=RANKS, engine="pallas", n_iter=N_ITER,
+        shard=tucker.ShardSpec(num_devices=4)))
+    res = plan(sk)
+    sched = next(iter(plan.engine.shard_schedules.values()))
+    mode0 = sched.scheds[0]
+    valid = np.asarray(mode0.valid).reshape(4, -1, mode0.bn).sum(axis=2)
+    blkmap = np.asarray(mode0.blkmap)
+    out["skew"] = {
+        **maxdiffs(res, ref),
+        # (shard, row block) pairs streamed only as padding: row blocks the
+        # shard never visits, which the kernel still zeroes
+        "unvisited": sorted({(s, int(blkmap[s, b])) for s in range(4)
+                             for b in range(blkmap.shape[1])} - {
+                            (s, int(blkmap[s, b])) for s in range(4)
+                            for b in range(blkmap.shape[1]) if valid[s, b] > 0}),
+        "n_row_blocks": mode0.n_row_blocks,
+        "groups": [sorted(set(blkmap[s].tolist())) for s in range(4)],
+    }
+
+    # -- the resumable segment program on Pallas ------------------------------
+    with tempfile.TemporaryDirectory() as snap_dir:
+        res = tucker.plan(tucker.TuckerSpec(
+            shape=SHAPE, ranks=RANKS, method="gram", engine="pallas",
+            n_iter=N_ITER, shard=tucker.ShardSpec(num_devices=4),
+            snapshot=tucker.SnapshotSpec(every_n_sweeps=2,
+                                         directory=snap_dir)))(coo)
+    out["pallas_segments"] = {**maxdiffs(res, refs["xla", "gram"]),
+                              "engine": res.engine, "n_sweeps": res.n_sweeps,
+                              "dispatches": res.dispatches}
 
     # -- no-retrace when only nnz values change (same indices object) -------
     spec = tucker.TuckerSpec(shape=SHAPE, ranks=RANKS, method="gram",
@@ -107,7 +174,7 @@ _MATRIX_SCRIPT = """
         # 397 real nnz over 4 shards of 256 slots: some shard is all padding
         "imbalance_r1": r1.shard_imbalance,
         "fit_maxdiff_vs_unpadded": float(np.abs(
-            r1.fit_history - refs["gram"].fit_history).max()),
+            r1.fit_history - refs["xla", "gram"].fit_history).max()),
     }
 
     # -- tol early-exit parity on the sharded program ------------------------
@@ -132,6 +199,7 @@ def matrix():
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     code = textwrap.dedent(_MATRIX_SCRIPT % {
         "devices": DEVICE_COUNTS, "methods": METHODS, "nnz": RAGGED_NNZ,
+        "engines": ENGINES,
     })
     out = subprocess.run(
         [sys.executable, "-c", code],
@@ -146,18 +214,82 @@ def test_forced_host_device_count(matrix):
     assert matrix["n_devices"] == 4
 
 
+def _case(matrix, devices, method, engine="xla"):
+    return next(c for c in matrix["cases"] if c["devices"] == devices
+                and c["method"] == method and c["engine"] == engine)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("devices", DEVICE_COUNTS)
 @pytest.mark.parametrize("method", METHODS)
 def test_sharded_matches_single_device(matrix, devices, method):
     """Factors/core/fit parity with the single-device pipeline across
     device counts x methods on ragged nnz (the tentpole acceptance gate)."""
-    case = next(c for c in matrix["cases"]
-                if c["devices"] == devices and c["method"] == method)
+    case = _case(matrix, devices, method)
     assert case["fit_maxdiff"] < 1e-5
     assert case["core_maxdiff"] < 5e-4
     assert case["factor_maxdiff"] < 5e-4
     assert case["n_sweeps"] == 3
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("devices", DEVICE_COUNTS)
+@pytest.mark.parametrize("method", METHODS)
+def test_sharded_pallas_matches_single_device(matrix, devices, method):
+    """engine='pallas' under shard: each device streams its own nonzeros
+    through the fused Kron-scatter kernel, and the psummed result matches
+    the single-device XLA and Pallas programs."""
+    case = _case(matrix, devices, method, engine="pallas")
+    for diffs in (case, case["vs_single_engine"]):
+        assert diffs["fit_maxdiff"] < 1e-5
+        assert diffs["core_maxdiff"] < 5e-4
+        assert diffs["factor_maxdiff"] < 5e-4
+    assert case["n_sweeps"] == 3 and case["dispatches"] == 1
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("devices", DEVICE_COUNTS)
+def test_sharded_pallas_layout_spans(matrix, devices):
+    """One ``shard_layout`` build, span and counter tick per shard and mode;
+    each span's blocks plus the blocks appended make the stacked count, and
+    the fullest shard of a mode gets none appended. The XLA engine builds
+    no per-shard schedule."""
+    for method in METHODS:
+        assert _case(matrix, devices, method)["layout_builds"] == 0
+        case = _case(matrix, devices, method, engine="pallas")
+        assert case["layout_builds"] == devices * 3
+        spans = case["layout_spans"]
+        assert sorted((s, m) for s, m, _, _ in spans) == [
+            (s, m) for s in range(devices) for m in range(3)]
+        for m, (n_shards, n_blocks) in enumerate(case["stacked_blocks"]):
+            assert n_shards == devices
+            mine = [(own, pad) for _, mode, own, pad in spans if mode == m]
+            assert all(own + pad == n_blocks for own, pad in mine)
+            assert min(pad for _, pad in mine) == 0
+
+
+@pytest.mark.slow
+def test_sharded_pallas_zeroes_unvisited_row_blocks(matrix):
+    """A shard that never visits a row block streams one all-padding block
+    for it, so its kernel writes zeros there: every shard holds one group
+    per row block, and the result matches the single-device program."""
+    sk = matrix["skew"]
+    assert sk["unvisited"]  # the skew leaves some shard's row blocks empty
+    assert all(g == list(range(sk["n_row_blocks"])) for g in sk["groups"])
+    assert sk["fit_maxdiff"] < 1e-5
+    assert sk["core_maxdiff"] < 5e-4
+    assert sk["factor_maxdiff"] < 5e-4
+
+
+@pytest.mark.slow
+def test_sharded_pallas_segments_match_single_device(matrix):
+    """The resumable sharded segment program runs the Pallas engine too."""
+    seg = matrix["pallas_segments"]
+    assert seg["engine"] == "pallas"
+    assert seg["n_sweeps"] == 3 and seg["dispatches"] == 2
+    assert seg["fit_maxdiff"] < 1e-5
+    assert seg["core_maxdiff"] < 5e-4
+    assert seg["factor_maxdiff"] < 5e-4
 
 
 @pytest.mark.slow
@@ -240,8 +372,8 @@ def test_tucker_spec_shard_constraints():
 
     shard = tucker.ShardSpec(num_devices=1)
     kw = dict(shape=(8, 8, 8), ranks=(2, 2, 2), shard=shard)
-    with pytest.raises(ValueError, match="XLA engine"):
-        tucker.TuckerSpec(engine="pallas", **kw)
+    # each device runs the Pallas kernels on its own shard
+    assert tucker.TuckerSpec(engine="pallas", **kw).engine == "pallas"
     with pytest.raises(ValueError, match="kron_reuse"):
         tucker.TuckerSpec(use_kron_reuse=True, **kw)
     with pytest.raises(ValueError, match="sparse"):

@@ -7,7 +7,7 @@ unsupported vector shape casts, misaligned blocks, too much VMEM, a program
 that does not fit HBM. These tests compile the kernels at real widths (R = 16,
 128-row blocks, nell-2's 12,092-row mode) with ``interpret=False``, at both
 precisions, plus one whole Pallas sweep program at 2^18 nonzeros of nell-2's
-shape.
+shape, on one chip and sharded over the four chips of a v5e 2x2 host.
 
 The topology is described inside a module fixture, never at import: only one
 process may hold libtpu, and under several test workers only the worker that
@@ -22,12 +22,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.core import hooi, stages
 from repro.core.coo import SparseCOO
 from repro.kernels import kron_kernel, ttm_kernel
-from repro.sparse.layout import DeviceSchedule, build_mode_layout
+from repro.sparse.layout import (
+    DeviceSchedule, build_mode_layout, shard_block_count, shard_rows,
+)
 from repro.utils import hlo
 
 R, BN, BI, ROWS = 16, 128, 128, 12_092
@@ -38,13 +40,13 @@ PRECISIONS = ("fp32", "bf16_fp32acc")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(
+        described = topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
         )
     except Exception as e:  # no TPU compiler in this installation
@@ -54,9 +56,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield described
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _sds(sharding, shape, dtype=jnp.float32):
@@ -258,3 +265,61 @@ def mosaic_kernels(text):
             out.append(module.operation.get_asm(enable_debug_info=False))
     assert out
     return out
+
+
+SHARD_NNZ = 2**18  # nonzeros a chip of the sharded program
+
+
+@pytest.mark.parametrize("engine", ["xla", "pallas"])
+def test_sharded_program_compiles_for_v5e_2x2(topo, engine):
+    """The whole 5-sweep sharded program of a nell-2 plan over the four
+    chips of a v5e 2x2 host, at 2^18 nonzeros a chip: on Pallas each chip
+    runs the Mosaic kernels on its own shard's schedule, with no
+    ``(nnz, 256)`` Kron operand in HBM, where the XLA engine builds one;
+    both psum each mode's unfolding once a sweep."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("nnz",))
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    scheds = None
+    if engine == "pallas":
+        idx = np.asarray(_nell2_coo(4 * SHARD_NNZ).indices)
+        scheds = []
+        for m in range(3):
+            n_blocks = max(shard_block_count(shard_rows(idx, 4 * SHARD_NNZ, 4, s, m),
+                                             NELL2[m], BN, BI) for s in range(4))
+
+            def leaf(n, dtype=jnp.int32):
+                return sds((4, n), dtype, P("nnz"))
+
+            scheds.append(DeviceSchedule(
+                order=leaf(n_blocks * BN), valid=leaf(n_blocks * BN, jnp.float32),
+                rel_row=leaf(n_blocks * BN), blkmap=leaf(n_blocks),
+                first=leaf(n_blocks), last=leaf(n_blocks), row_mask=None,
+                kron_unique=None, kron_inverse=None, mode=m, shape=NELL2,
+                n_row_blocks=-(-NELL2[m] // BI), bn=BN, bi=BI, kron_modes=None,
+            ))
+        scheds = tuple(scheds)
+    prog = hooi.build_sharded_program(
+        mesh, ("nnz",), shape=NELL2, ranks=(R, R, R), method="householder",
+        n_iter=5, engine_name=engine, interpret=False,
+    )
+    scalar = sds((), jnp.float32, P())
+    text = prog.lower(
+        sds((4 * SHARD_NNZ, 3), jnp.int32, P("nnz", None)),
+        sds((4 * SHARD_NNZ,), jnp.float32, P("nnz")),
+        tuple(sds((NELL2[m], R), jnp.float32, P(None, None)) for m in range(3)),
+        scalar, scalar, scheds,
+    ).compile().as_text()
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    kron_operands = [int(n) for n in re.findall(r"f32\[(\d+),256\]", text)
+                     if int(n) >= SHARD_NNZ]
+    if engine == "pallas":
+        assert kernels == 4  # a fused Kron-scatter kernel a mode, the core TTM
+        assert not kron_operands
+    else:
+        assert kernels == 0 and kron_operands
+    all_reduces = [ln for ln in loop_lines(text)
+                   if re.search(r" all-reduce(-start)?\(", ln)]
+    assert len(all_reduces) == 3
